@@ -30,10 +30,13 @@ smaller, so under a budget set to 1/8 of the float footprint the float
 deployment needs 8 devices while a whole packed replica fits on 1
 (serving.replica.devices_needed, measured from real resident bytes).
 
-The measurement runs in a SUBPROCESS: XLA_FLAGS must be set before jax
-initializes, and benchmarks/run.py has long since imported jax by the
-time it reaches this module. Parent parses the child's JSON and records
-BENCH_sharded_serving.json.
+On an accelerator the measurement runs in this process, over the real
+devices (it needs N_DEV of them): a chip belongs to one process, and
+benchmarks/run.py has already taken it. On the CPU backend it runs in a
+SUBPROCESS with forced host devices: XLA_FLAGS must be set before jax
+initializes, and run.py has long since imported jax by the time it
+reaches this module; the parent parses the child's JSON. Either way the
+result is recorded in BENCH_sharded_serving.json.
 """
 from __future__ import annotations
 
@@ -132,7 +135,7 @@ def _measure(smoke: bool) -> dict:
     return out
 
 
-def run(smoke: bool = False) -> list[tuple[str, float, str]]:
+def _measure_in_child(smoke: bool) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={N_DEV}"
@@ -147,7 +150,18 @@ def run(smoke: bool = False) -> list[tuple[str, float, str]]:
     if proc.returncode != 0:
         raise RuntimeError(
             f"sharded-serving child failed:\n{proc.stdout}\n{proc.stderr}")
-    m = json.loads(proc.stdout.strip().splitlines()[-1])
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def run(smoke: bool = False) -> list[tuple[str, float, str]]:
+    import jax
+    if jax.default_backend() == "cpu":
+        m = _measure_in_child(smoke)
+    else:
+        if len(jax.devices()) < N_DEV:
+            raise RuntimeError(f"sharded serving needs {N_DEV} devices, "
+                               f"found {len(jax.devices())}")
+        m = _measure(smoke)
 
     rows = [
         ("sharded_token_identity", 0.0,

@@ -59,6 +59,8 @@ def main() -> None:
         bench_saturation, bench_sharded_serving,
     )
     from benchmarks._record import record
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = [bench_energy, bench_binary_gemm, bench_packed_serving,
             bench_continuous_serving, bench_prefill_interleave,
             bench_prefix_cache, bench_resilience, bench_sharded_serving,

@@ -40,9 +40,12 @@ def pack_bits(x: Array) -> Array:
         bits = jnp.concatenate(
             [bits, jnp.ones(x.shape[:-1] + (pad,), dtype=bits.dtype)], axis=-1
         )
-    bits = bits.reshape(x.shape[:-1] + (kw, WORD)).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jnp.arange(WORD, dtype=jnp.uint32))
-    return jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
+    bits = bits.reshape(x.shape[:-1] + (kw, WORD)).astype(jnp.int32)
+    weights = jnp.left_shift(jnp.int32(1), jnp.arange(WORD, dtype=jnp.int32))
+    # the bits are disjoint, so the int32 sum is their OR; bitcast gives the
+    # uint32 word (no unsigned reduction, which the TPU compiler lacks)
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(bits * weights, axis=-1, dtype=jnp.int32), jnp.uint32)
 
 
 def unpack_bits(p: Array, k: int, dtype=jnp.float32) -> Array:

@@ -11,13 +11,32 @@ the clamping/padding rules instead of three hand-copied variants.
 All inputs and outputs are plain Python ints (static shapes), never
 traced values — the cache key is hashable by construction and the
 results feed BlockSpecs/grids, which must be static anyway.
+
+`aligned=True` (every kernel passes `not interpret`) also applies the TPU
+compiler's block rule: the last two dims of every block are multiples of
+(8, 128) or span the whole array dim. A proposed tile that breaks it is
+rounded up to the next legal edge (or to the whole dim). Interpret mode
+has no such rule, so the CPU tests keep exercising ragged tiles.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 from repro.core.bitpack import WORD
+
+
+SUBLANE, LANE = 8, 128
+
+
+def _fit(block: int, dim: int, align: int) -> int:
+    """Clamp a proposed block edge to `dim`; a partial block is rounded up
+    to a multiple of `align` (1 = any edge), or to the whole dim."""
+    if block >= dim:
+        return dim
+    block = -(-block // align) * align
+    return dim if block >= dim else block
 
 
 class GemmGeometry(NamedTuple):
@@ -36,11 +55,14 @@ class GemmGeometry(NamedTuple):
 
 @functools.lru_cache(maxsize=None)
 def gemm_geometry(m: int, n: int, kw: int, bm: int, bn: int, bk: int,
-                  uk: int = 1) -> GemmGeometry:
-    """Geometry for binary_gemm_vpu{,_packed}: blocks clamped to the
-    operand, pads up to block multiples, grid sizes, and the inner-loop
-    word-chunk width `uk` clamped to divide bk."""
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, kw)
+                  uk: int = 1, *, aligned: bool = False) -> GemmGeometry:
+    """Geometry for binary_gemm_vpu{,_packed} (and the MXU kernel, with
+    kw the float K): blocks clamped to the operand, pads up to block
+    multiples, grid sizes, and the inner-loop word-chunk width `uk`
+    clamped to divide bk. Aligned: bm is a sublane edge (lhs/out rows),
+    bn and bk are lane edges (out columns, word columns)."""
+    sub, lane = (SUBLANE, LANE) if aligned else (1, 1)
+    bm, bn, bk = _fit(bm, m, sub), _fit(bn, n, lane), _fit(bk, kw, lane)
     uk = min(uk, bk) if uk > 0 else 0
     if uk > 0:
         while bk % uk:           # uk must tile bk exactly
@@ -52,16 +74,18 @@ def gemm_geometry(m: int, n: int, kw: int, bm: int, bn: int, bk: int,
 
 @functools.lru_cache(maxsize=None)
 def fused_gemm_geometry(m: int, n: int, kw: int, bm: int, bn: int,
-                        uk: int = 0) -> GemmGeometry:
+                        uk: int = 0, *, aligned: bool = False) -> GemmGeometry:
     """Geometry for binary_gemm_vpu_packed_io: K stays whole per block
     (bk == kw), bn is clamped to a multiple of 32 (the N-axis repack
     width), and `uk` is clamped to a divisor of kw — the fused kernel's
     inner fori_loop runs kw//uk steps, so a non-divisor uk would silently
     drop the kw%uk trailing words (same rule gemm_geometry applies to
-    uk vs bk)."""
+    uk vs bk). Aligned: the output block is (bm, bn/32) words, so a
+    partial bn is a multiple of 32 * 128 bits."""
     assert bn % WORD == 0, f"bn must be a multiple of {WORD} (N repack): {bn}"
-    bm = min(bm, m)
-    bn = min(bn, ((n + WORD - 1) // WORD) * WORD)
+    bm = _fit(bm, m, SUBLANE if aligned else 1)
+    bn = _fit(bn, ((n + WORD - 1) // WORD) * WORD,
+              WORD * LANE if aligned else 1)
     uk = min(uk, kw) if uk > 0 else 0
     if uk > 0:
         while kw % uk:           # uk must tile the whole-K block exactly
@@ -83,11 +107,15 @@ class AttnGeometry(NamedTuple):
 
 
 @functools.lru_cache(maxsize=None)
-def attn_geometry(b: int, s: int, block_b: int, block_q: int) -> AttnGeometry:
+def attn_geometry(b: int, s: int, block_b: int, block_q: int, *,
+                  group: int = 1, aligned: bool = False) -> AttnGeometry:
     """Shared decode/prefill attention geometry. Decode passes s == 1,
-    block_q == 1; prefill tiles both axes."""
+    block_q == 1; prefill tiles both axes. A query block holds
+    block_q * group score rows (GQA heads ride along); aligned, a partial
+    block holds a multiple of 8 of them."""
     bb = max(1, min(block_b, b))
-    bq = max(1, min(block_q, s))
+    step = SUBLANE // math.gcd(SUBLANE, group) if aligned else 1
+    bq = _fit(max(1, block_q), s, step)
     pb, ps = (-b) % bb, (-s) % bq
     return AttnGeometry(bb, bq, pb, ps, (b + pb) // bb, (s + ps) // bq)
 

@@ -8,9 +8,8 @@ dispatch layer that picks between them per shape:
     (wire format of repro.core.bitpack). The kernel tiles (bm, bn) output
     blocks into VMEM, streams (bm, bk)/(bn, bk) word-tiles, and
     accumulates popcount(xor(a, b)) on the VPU's 8x128 int lanes (the
-    honest analogue of __popc-based SIMT kernels). `uk` controls how many
-    K-words feed the lanes per inner step — uk=0 broadcasts the whole
-    (bm, bn, bk) tile at once.
+    honest analogue of __popc-based SIMT kernels). `uk` is how many K-words
+    the inner loop unrolls per step — uk=0 unrolls the whole tile.
 
   * `binary_gemm_mxu` — fused binarize-then-matmul: float tiles are
     sign-quantized to +-1 bf16 *in VMEM* and fed to the MXU's 128x128
@@ -55,53 +54,94 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bitpack import WORD, pack_bits, unpack_bits
 from repro.core.packed import ALWAYS_THRESH
 from repro.kernels import ref
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels._geometry import fused_gemm_geometry, gemm_geometry
+from repro.kernels.pack import pack_lanes
 
 Array = jax.Array
 
 
-def _popcount_outer(aw: Array, bw: Array, acc: Array, uk: int) -> Array:
-    """acc (bm, bn) += sum_w popcount(xor(aw[:, w], bw[:, w])) — the XNOR
+def _i32(x: Array) -> Array:
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _popcount_outer(a_words: Array, b_words: Array, acc: Array, at_ref,
+                    bt_ref, uk: int) -> Array:
+    """acc (bm, bn) += sum_w popcount(xor(a[:, w], b[:, w])) — the XNOR
     inner product over (bm, bk) x (bn, bk) word tiles.
 
-    `uk` is the number of K-words fed to the popcount lanes per inner
-    step: uk == 1 is the word-at-a-time outer product (lowest VMEM
-    pressure, underfills the 8x128 lanes at small bk), larger uk streams
-    a (bm, bn, uk) sliver per step, and uk == 0 (or >= bk) broadcasts the
-    whole (bm, bn, bk) tile in one shot. All variants are exact — integer
-    adds commute — so uk is purely a performance knob for the autotuner.
+    Both tiles are transposed into VMEM scratch once, so each K-word is a
+    row (1, bn) of b and a column (bm, 1) of a: one xor, popcount and add
+    over the (bm, bn) accumulator per word, all on the VPU lanes. `uk` is
+    the number of words unrolled per step of the inner loop: 0 (or >= bk)
+    unrolls the whole tile; otherwise a fori_loop runs bk // uk steps. All
+    variants are exact — integer adds commute — so uk is purely a
+    performance knob for the autotuner.
     """
-    bk = aw.shape[1]
+    at_ref[...] = _i32(a_words).T
+    bt_ref[...] = _i32(b_words).T
+    bk = bt_ref.shape[0]
+
+    def word(w, acc):
+        a = at_ref[pl.ds(w, 1), :].T                         # (bm, 1)
+        return acc + jax.lax.population_count(a ^ bt_ref[pl.ds(w, 1), :])
+
     if uk <= 0 or uk >= bk:
-        x = jnp.bitwise_xor(aw[:, None, :], bw[None, :, :])
-        return acc + jnp.sum(jax.lax.population_count(x).astype(jnp.int32),
-                             axis=-1)
+        for w in range(bk):
+            acc = word(w, acc)
+        return acc
 
-    def body(c, acc):
-        a = jax.lax.dynamic_slice_in_dim(aw, c * uk, uk, 1)
-        b = jax.lax.dynamic_slice_in_dim(bw, c * uk, uk, 1)
-        x = jnp.bitwise_xor(a[:, None, :], b[None, :, :])
-        return acc + jnp.sum(jax.lax.population_count(x).astype(jnp.int32),
-                             axis=-1)
+    def step(c, acc):
+        for i in range(uk):
+            acc = word(c * uk + i, acc)
+        return acc
 
-    return jax.lax.fori_loop(0, bk // uk, body, acc)
+    return jax.lax.fori_loop(0, bk // uk, step, acc)
+
+
+def _word_scratch(bm: int, bn: int, bk: int) -> list:
+    return [pltpu.VMEM((bk, bm), jnp.int32), pltpu.VMEM((bk, bn), jnp.int32)]
 
 
 # ---------------------------------------------------------------------------
 # VPU popcount kernel over packed uint32 words
 # ---------------------------------------------------------------------------
-def _vpu_kernel(a_ref, b_ref, o_ref, *, k_true: int, nk: int, uk: int):
-    """a_ref: (bm, bk) uint32, b_ref: (bn, bk) uint32, o_ref: (bm, bn) int32."""
+def _vpu_kernel(a_ref, b_ref, o_ref, at_ref, bt_ref, *, k_true: int, nk: int,
+                uk: int, pack_lhs: bool):
+    """a_ref: (bm, bk) uint32 — or (bm, bk*32) float when `pack_lhs`,
+    sign-packed here in VMEM; b_ref: (bn, bk) uint32; o_ref: (bm, bn)
+    int32, revisited across the K grid axis as the accumulator."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    acc = _popcount_outer(a_ref[...], b_ref[...], o_ref[...], uk)
+    a = a_ref[...]
+    aw = pack_lanes(a.astype(jnp.float32) >= 0) if pack_lhs else a
+    acc = _popcount_outer(aw, b_ref[...], o_ref[...], at_ref, bt_ref, uk)
     is_last = pl.program_id(2) == nk - 1
     # fold the K - 2*acc epilogue into the final K-step
     o_ref[...] = jnp.where(is_last, jnp.int32(k_true) - 2 * acc, acc)
+
+
+def _vpu_call(a: Array, b_packed: Array, k_true: int, geo, *,
+              pack_lhs: bool, interpret: bool) -> Array:
+    ka = geo.bk * WORD if pack_lhs else geo.bk
+    return pl.pallas_call(
+        functools.partial(_vpu_kernel, k_true=k_true, nk=geo.gk, uk=geo.uk,
+                          pack_lhs=pack_lhs),
+        grid=(geo.gm, geo.gn, geo.gk),
+        in_specs=[
+            pl.BlockSpec((geo.bm, ka), lambda i, j, k: (i, k)),
+            pl.BlockSpec((geo.bn, geo.bk), lambda i, j, k: (j, k)),
+        ],
+        out_specs=pl.BlockSpec((geo.bm, geo.bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((a.shape[0], b_packed.shape[0]),
+                                       jnp.int32),
+        scratch_shapes=_word_scratch(geo.bm, geo.bn, geo.bk),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(a, b_packed)
 
 
 def binary_gemm_vpu(a_packed: Array, b_packed: Array, k_true: int, *,
@@ -115,28 +155,15 @@ def binary_gemm_vpu(a_packed: Array, b_packed: Array, k_true: int, *,
     m, kw = a_packed.shape
     n, kw2 = b_packed.shape
     assert kw == kw2, (kw, kw2)
-    geo = gemm_geometry(m, n, kw, bm, bn, bk, uk)
+    geo = gemm_geometry(m, n, kw, bm, bn, bk, uk, aligned=not interpret)
     # pad with identical words so xor(pad, pad) == 0 in the K direction;
     # M/N padding rows are sliced off after the call.
     if geo.pm or geo.pk:
         a_packed = jnp.pad(a_packed, ((0, geo.pm), (0, geo.pk)))
     if geo.pn or geo.pk:
         b_packed = jnp.pad(b_packed, ((0, geo.pn), (0, geo.pk)))
-
-    out = pl.pallas_call(
-        functools.partial(_vpu_kernel, k_true=k_true, nk=geo.gk, uk=geo.uk),
-        grid=(geo.gm, geo.gn, geo.gk),
-        in_specs=[
-            pl.BlockSpec((geo.bm, geo.bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((geo.bn, geo.bk), lambda i, j, k: (j, k)),
-        ],
-        out_specs=pl.BlockSpec((geo.bm, geo.bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((a_packed.shape[0], b_packed.shape[0]),
-                                       jnp.int32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(a_packed, b_packed)
+    out = _vpu_call(a_packed, b_packed, k_true, geo, pack_lhs=False,
+                    interpret=interpret)
     return out[:m, :n]
 
 
@@ -146,23 +173,6 @@ def binary_gemm_vpu(a_packed: Array, b_packed: Array, k_true: int, *,
 # only the float activations get sign-packed here — in VMEM, fused with the
 # xor/popcount accumulation, never materializing packed activations to HBM.
 # ---------------------------------------------------------------------------
-def _vpu_packed_rhs_kernel(a_ref, b_ref, o_ref, *, k_true: int, nk: int,
-                           uk: int):
-    """a_ref: (bm, bk*32) float, b_ref: (bn, bk) uint32, o_ref: (bm, bn) i32."""
-
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # sign-pack the float activation block in VMEM; the block is already
-    # word-aligned, so bitpack's pure-jnp packer (the wire format's single
-    # source of truth) traces fine inside the kernel
-    aw = pack_bits(a_ref[...])                               # (bm, bk)
-    acc = _popcount_outer(aw, b_ref[...], o_ref[...], uk)
-    is_last = pl.program_id(2) == nk - 1
-    o_ref[...] = jnp.where(is_last, jnp.int32(k_true) - 2 * acc, acc)
-
-
 def binary_gemm_vpu_packed(a: Array, b_packed: Array, k_true: int, *,
                            bm: int = 128, bn: int = 128, bk: int = 8,
                            uk: int = 1,
@@ -182,29 +192,15 @@ def binary_gemm_vpu_packed(a: Array, b_packed: Array, k_true: int, *,
     # pad bits of b, so xor(pad, pad) == 0 contributes nothing
     if kw * 32 - k:
         a = jnp.pad(a, ((0, 0), (0, kw * 32 - k)), constant_values=1.0)
-    geo = gemm_geometry(m, n, kw, bm, bn, bk, uk)
+    geo = gemm_geometry(m, n, kw, bm, bn, bk, uk, aligned=not interpret)
     # word-granular K padding: b grows zero words; a grows -1.0 columns,
     # which pack to the zero word, so xor(0, 0) == 0 again cancels.
     if geo.pm or geo.pk:
         a = jnp.pad(a, ((0, geo.pm), (0, geo.pk * 32)), constant_values=-1.0)
     if geo.pn or geo.pk:
         b_packed = jnp.pad(b_packed, ((0, geo.pn), (0, geo.pk)))
-
-    out = pl.pallas_call(
-        functools.partial(_vpu_packed_rhs_kernel, k_true=k_true, nk=geo.gk,
-                          uk=geo.uk),
-        grid=(geo.gm, geo.gn, geo.gk),
-        in_specs=[
-            pl.BlockSpec((geo.bm, geo.bk * 32), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((geo.bn, geo.bk), lambda i, j, kk: (j, kk)),
-        ],
-        out_specs=pl.BlockSpec((geo.bm, geo.bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((a.shape[0], b_packed.shape[0]),
-                                       jnp.int32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(a, b_packed)
+    out = _vpu_call(a, b_packed, k_true, geo, pack_lhs=True,
+                    interpret=interpret)
     return out[:m, :n]
 
 
@@ -223,20 +219,16 @@ def binary_gemm_vpu_packed(a: Array, b_packed: Array, k_true: int, *,
 # the grid is (M, N)-parallel only and no cross-step accumulator state is
 # needed.
 # ---------------------------------------------------------------------------
-def _fused_epilogue_kernel(a_ref, b_ref, t_ref, f_ref, o_ref, *, k_true: int,
-                           packed_lhs: bool, uk: int):
+def _fused_epilogue_kernel(a_ref, b_ref, t_ref, f_ref, o_ref, at_ref, bt_ref,
+                           *, k_true: int, packed_lhs: bool, uk: int):
     """a_ref: (bm, kw) uint32 | (bm, kw*32) float; b_ref: (bn, kw) uint32;
     t_ref/f_ref: (1, bn) int32; o_ref: (bm, bn//32) uint32."""
-    aw = a_ref[...] if packed_lhs else pack_bits(a_ref[...])   # (bm, kw)
-    b = b_ref[...]
-    bm = aw.shape[0]
-    bn = b.shape[0]
-    acc = _popcount_outer(aw, b, jnp.zeros((bm, bn), jnp.int32), uk)
+    a = a_ref[...]
+    aw = a if packed_lhs else pack_lanes(a.astype(jnp.float32) >= 0)
+    acc = jnp.zeros((aw.shape[0], b_ref.shape[0]), jnp.int32)
+    acc = _popcount_outer(aw, b_ref[...], acc, at_ref, bt_ref, uk)
     dot = jnp.int32(k_true) - 2 * acc
-    bits = (dot >= t_ref[...]) != (f_ref[...] != 0)            # (bm, bn) bool
-    words = bits.reshape(bm, bn // WORD, WORD).astype(jnp.uint32)
-    weights = jnp.uint32(1) << jnp.arange(WORD, dtype=jnp.uint32)
-    o_ref[...] = jnp.sum(words * weights, axis=-1, dtype=jnp.uint32)
+    o_ref[...] = pack_lanes((dot >= t_ref[...]) != (f_ref[...] != 0))
 
 
 def binary_gemm_vpu_packed_io(a: Array, b_packed: Array, thresh: Array,
@@ -266,7 +258,7 @@ def binary_gemm_vpu_packed_io(a: Array, b_packed: Array, thresh: Array,
         if kw * WORD - k_true:
             a = jnp.pad(a, ((0, 0), (0, kw * WORD - k_true)),
                         constant_values=1.0)
-    geo = fused_gemm_geometry(m, n, kw, bm, bn, uk)
+    geo = fused_gemm_geometry(m, n, kw, bm, bn, uk, aligned=not interpret)
     if geo.pm:
         a = jnp.pad(a, ((0, geo.pm), (0, 0)),
                     constant_values=0 if packed_lhs else -1.0)
@@ -293,7 +285,8 @@ def binary_gemm_vpu_packed_io(a: Array, b_packed: Array, thresh: Array,
         out_specs=pl.BlockSpec((bm, bn // WORD), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(
             (a.shape[0], b_packed.shape[0] // WORD), jnp.uint32),
-        compiler_params=_CompilerParams(
+        scratch_shapes=_word_scratch(bm, bn, kw),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(a, b_packed, thresh[None, :], flip[None, :])
@@ -324,7 +317,7 @@ def binary_gemm_mxu(x: Array, w: Array, *, bm: int = 128, bn: int = 128,
     m, k = x.shape
     k2, n = w.shape
     assert k == k2
-    geo = gemm_geometry(m, n, k, bm, bn, bk)
+    geo = gemm_geometry(m, n, k, bm, bn, bk, aligned=not interpret)
     if geo.pm or geo.pk:
         # K padding scheme: pad x's K-cols AND w's K-rows with +1.0, so each
         # pad position contributes sign(+1)*sign(+1) = +1 to every dot;
@@ -343,7 +336,7 @@ def binary_gemm_mxu(x: Array, w: Array, *, bm: int = 128, bn: int = 128,
         ],
         out_specs=pl.BlockSpec((geo.bm, geo.bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], w.shape[1]), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

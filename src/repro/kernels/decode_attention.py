@@ -25,39 +25,31 @@ Float K/V are never materialized in HBM: HBM traffic per decode step drops
 from `2*B*T*Hkv*hd*itemsize` to `2*B*T*Hkv*ceil(hd/32)*4` bytes (~32x for
 fp32 caches at hd >= 32).
 
-Grid is (B/block_b, Hkv): each program owns `block_b` batch rows of one kv
-head and their full (T, hdw) K/V panels in VMEM. `block_b` is an autotuned
-knob (repro.kernels.tune) — one row per program maximizes grid parallelism,
-several rows per program amortize per-program overhead and keep the 8x128
-popcount lanes full when B is the only parallel axis that matters at
-serving shapes. T-chunked online softmax is not needed at serving cache
-lengths (T*hdw words is ~1/32 the float cache a single fused attention row
-already streamed). GQA query heads for the kv head ride in the same block.
+The kernel is the chunked-prefill kernel at S == 1
+(`kernels.prefill_attention.packed_attention` with q_pos = cache_len - 1,
+whose causal mask is then exactly `t < cache_len`): grid (B/block_b, Hkv),
+each program owning `block_b` batch rows of one kv head and their full
+(hdw, T) K/V panels in VMEM. `block_b` is an autotuned knob
+(repro.kernels.tune) — one row per program maximizes grid parallelism,
+several rows per program amortize per-program overhead. GQA query heads
+for the kv head ride in the same block.
 
 `decode_attention_packed` is the dispatching entry point: `route=None`
 consults the tuning cache, which may pick this Pallas kernel ('pallas',
 with a tuned `block_b`) or the XLA-lowered packed formulation ('xla', the
 oracle itself — on hosts where Pallas runs in interpret mode, letting XLA
-compile the popcount einsum is the fast packed path). Both routes are
-bit-exact by construction: semantics are defined by
-`repro.kernels.ref.decode_attention_packed_ref`, and the kernel is
-asserted bit-exact against it for every block_b the autotuner may pick
-(tests/test_decode_attention_packed.py), so the float op sequence here
-deliberately mirrors the oracle op for op.
+compile the popcount einsum is the fast packed path). Semantics are
+defined by `repro.kernels.ref.decode_attention_packed_ref`; the routes
+agree on the integer scores exactly and on the output up to f32 rounding
+of the softmax and V sums (tests/test_decode_attention_packed.py).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.core.bitpack import pack_bits, unpack_bits
 from repro.kernels import ref
-from repro.kernels._compat import CompilerParams as _CompilerParams
-from repro.kernels._geometry import attn_geometry
-from repro.kernels.ref import NEG_INF
+from repro.kernels.prefill_attention import packed_attention
 
 Array = jax.Array
 
@@ -70,68 +62,6 @@ def v_cache_scale(v: Array) -> Array:
     definition for every family that packs a cache (transformer KV, hybrid
     ring buffer), so their wire formats cannot drift."""
     return jnp.mean(jnp.abs(v.astype(jnp.float32)), axis=(1, 3))
-
-
-def _attend_decode(qb, kb, vb, lens, vs, *, hd: int, hdw: int, window: int):
-    """Shared decode-attention core: qb (bb,G,hdw) uint32, kb/vb (bb,T,hdw)
-    uint32, lens/vs (bb,1); returns (bb,G,hd) f32. The contiguous and paged
-    kernels both end here — the paged variant only changes how kb/vb were
-    *addressed* (gathered from the page pool), never the float op sequence,
-    which is what makes paged == contiguous bit-exact at equal T."""
-    bb, t = kb.shape[0], kb.shape[1]
-    g = qb.shape[1]
-
-    def body(w, acc):
-        x = jnp.bitwise_xor(qb[:, :, w][:, :, None], kb[:, :, w][:, None, :])
-        return acc + jax.lax.population_count(x).astype(jnp.int32)
-
-    acc = jax.lax.fori_loop(0, hdw, body, jnp.zeros((bb, g, t), jnp.int32))
-    dots = jnp.int32(hd) - 2 * acc                             # sign dot
-    s = dots.astype(jnp.float32) * jnp.float32(1.0 / float(hd) ** 0.5)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
-    length = lens[:, :, None]                                  # (bb, 1, 1)
-    valid = pos < length                                       # (bb, 1, T)
-    if window > 0:
-        valid &= pos >= length - window
-    s = jnp.where(valid, s, NEG_INF)                           # (bb, G, T)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)                                         # masked -> 0.0
-    l = jnp.sum(e, axis=-1, keepdims=True)                     # (bb, G, 1)
-    sgn = unpack_bits(vb, hd)                                  # (bb, T, hd)
-    accv = jnp.sum(e[:, :, :, None] * sgn[:, None, :, :], axis=2)
-    return vs[:, :, None] * (accv / l)                         # (bb, G, hd)
-
-
-def _decode_packed_kernel(len_ref, q_ref, k_ref, v_ref, s_ref, o_ref, *,
-                          hd: int, hdw: int, window: int):
-    """`bb` batch rows of one kv head: q_ref (bb,1,G,hdw) uint32,
-    k_ref/v_ref (bb,1,T,hdw) uint32, len_ref (bb,1) int32, s_ref (bb,1)
-    f32, o_ref (bb,1,G,hd) f32."""
-    o_ref[:, 0] = _attend_decode(q_ref[:, 0], k_ref[:, 0], v_ref[:, 0],
-                                 len_ref[...], s_ref[...],
-                                 hd=hd, hdw=hdw, window=window)
-
-
-def _decode_packed_paged_kernel(len_ref, pt_ref, q_ref, kp_ref, vp_ref,
-                                s_ref, o_ref, *, hd: int, hdw: int,
-                                window: int):
-    """Paged twin of `_decode_packed_kernel`: kp_ref/vp_ref hold one kv
-    head's whole page pool (1, P, ps, hdw) and pt_ref the block's page
-    tables (bb, NP). The rows are gathered in VMEM into the same
-    (bb, NP*ps, hdw) panel shape the contiguous kernel reads, then the
-    shared core runs unchanged. Sentinel table entries (== P, unallocated)
-    clip to the last pool page; those garbage rows sit at positions
-    >= cache_len and the core's length mask drops them — the exact
-    convention the contiguous kernel already uses for rows past kv_len."""
-    pt = pt_ref[...]                                           # (bb, NP)
-    bb, np_ = pt.shape
-    p_pool, ps = kp_ref.shape[1], kp_ref.shape[2]
-    pid = jnp.minimum(pt, p_pool - 1).reshape(-1)              # (bb*NP,)
-    kb = jnp.take(kp_ref[0], pid, axis=0).reshape(bb, np_ * ps, hdw)
-    vb = jnp.take(vp_ref[0], pid, axis=0).reshape(bb, np_ * ps, hdw)
-    o_ref[:, 0] = _attend_decode(q_ref[:, 0], kb, vb,
-                                 len_ref[...], s_ref[...],
-                                 hd=hd, hdw=hdw, window=window)
 
 
 def decode_attention_packed(q: Array, k_packed: Array, v_packed: Array,
@@ -148,12 +78,12 @@ def decode_attention_packed(q: Array, k_packed: Array, v_packed: Array,
     cache_len: scalar or (B,) valid positions — the new token is already
     written at cache_len-1. Masks positions >= cache_len and, when
     window > 0, positions < cache_len - window. Returns (B, 1, Hq, hd) in
-    q.dtype, bit-exact with ref.decode_attention_packed_ref.
+    q.dtype, equal to ref.decode_attention_packed_ref up to f32 rounding
+    of the softmax and V sums.
 
     route=None consults the tuning cache ('pallas' with a tuned block_b,
     or 'xla'); an explicit route (+ block_b) bypasses it — tests and the
-    autotuner pin candidates that way. Every route computes identical
-    bits, so dispatch can never change results, only microseconds.
+    autotuner pin candidates that way.
     """
     b, t, hkv, hdw = k_packed.shape
     hd = q.shape[-1]
@@ -170,45 +100,10 @@ def decode_attention_packed(q: Array, k_packed: Array, v_packed: Array,
                                                window=window)
     if route != "pallas":
         raise ValueError(f"unknown decode_attention route: {route}")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-
-    qb = pack_bits(q.reshape(b, hkv, g, hd))                   # (B,Hkv,G,hdw)
-    kb = k_packed.transpose(0, 2, 1, 3)                        # (B,Hkv,T,hdw)
-    vb = v_packed.transpose(0, 2, 1, 3)
-    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1),
-                            (b,)).reshape(b, 1)
-    vs = v_scale.astype(jnp.float32)
-
-    geo = attn_geometry(b, 1, block_b or 1, 1)
-    bb = geo.bb
-    if geo.pb:
-        row_pad = ((0, geo.pb),) + ((0, 0),) * 3
-        qb, kb, vb = (jnp.pad(x, row_pad) for x in (qb, kb, vb))
-        # pad rows get length 1 (not 0): a zero-length row would softmax an
-        # all-NEG_INF score vector into 0/0 NaNs inside the shared block;
-        # length 1 keeps the math finite and the rows are sliced off below.
-        lens = jnp.pad(lens, ((0, geo.pb), (0, 0)), constant_values=1)
-        vs = jnp.pad(vs, ((0, geo.pb), (0, 0)))
-
-    out = pl.pallas_call(
-        functools.partial(_decode_packed_kernel, hd=hd, hdw=hdw,
-                          window=window),
-        grid=(geo.gb, hkv),
-        in_specs=[
-            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 1, g, hdw), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((bb, 1, t, hdw), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((bb, 1, t, hdw), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((bb, 1), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bb, 1, g, hd), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b + geo.pb, hkv, g, hd), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(lens, qb, kb, vb, vs)
-    return out[:b].reshape(b, 1, hkv * g, hd).astype(q.dtype)
+    lens = jnp.asarray(cache_len, jnp.int32)
+    return packed_attention(q, k_packed, v_packed, v_scale, lens, lens - 1,
+                            window=window, causal=True, block_q=1,
+                            block_b=block_b or 1, interpret=interpret)
 
 
 def decode_attention_packed_paged(q: Array, k_pool: Array, v_pool: Array,
@@ -224,10 +119,10 @@ def decode_attention_packed_paged(q: Array, k_pool: Array, v_pool: Array,
     mapping each slot's position range [i*ps, (i+1)*ps) to a pool page
     (entries == P are the unallocated sentinel — they clip to the last
     page and the garbage is masked by cache_len); v_scale: (B, Hkv);
-    cache_len: scalar or (B,). Returns (B, 1, Hq, hd) in q.dtype,
-    bit-exact with ref.decode_attention_packed_paged_ref — and with the
-    contiguous `decode_attention_packed` whenever NP*ps equals its T
-    (the kernels share `_attend_decode`; paging is pure addressing).
+    cache_len: scalar or (B,). Returns (B, 1, Hq, hd) in q.dtype, equal
+    to ref.decode_attention_packed_paged_ref up to f32 rounding — and
+    bit-exact with the contiguous `decode_attention_packed` whenever NP*ps
+    equals its T (one kernel core; paging is pure addressing).
     """
     p_pool, ps, hkv, hdw = k_pool.shape
     b, np_ = page_table.shape
@@ -245,44 +140,8 @@ def decode_attention_packed_paged(q: Array, k_pool: Array, v_pool: Array,
             q, k_pool, v_pool, v_scale, page_table, cache_len, window=window)
     if route != "pallas":
         raise ValueError(f"unknown decode_attention_paged route: {route}")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-
-    qb = pack_bits(q.reshape(b, hkv, g, hd))                   # (B,Hkv,G,hdw)
-    kp = k_pool.transpose(2, 0, 1, 3)                          # (Hkv,P,ps,hdw)
-    vp = v_pool.transpose(2, 0, 1, 3)
-    pt = jnp.asarray(page_table, jnp.int32)
-    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1),
-                            (b,)).reshape(b, 1)
-    vs = v_scale.astype(jnp.float32)
-
-    geo = attn_geometry(b, 1, block_b or 1, 1)
-    bb = geo.bb
-    if geo.pb:
-        qb = jnp.pad(qb, ((0, geo.pb),) + ((0, 0),) * 3)
-        # pad rows: length 1 (finite softmax, see contiguous kernel) and
-        # all-sentinel page tables — they clip to the last pool page, whose
-        # garbage words sit behind the length mask
-        lens = jnp.pad(lens, ((0, geo.pb), (0, 0)), constant_values=1)
-        pt = jnp.pad(pt, ((0, geo.pb), (0, 0)), constant_values=p_pool)
-        vs = jnp.pad(vs, ((0, geo.pb), (0, 0)))
-
-    out = pl.pallas_call(
-        functools.partial(_decode_packed_paged_kernel, hd=hd, hdw=hdw,
-                          window=window),
-        grid=(geo.gb, hkv),
-        in_specs=[
-            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, np_), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 1, g, hdw), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, p_pool, ps, hdw), lambda i, j: (j, 0, 0, 0)),
-            pl.BlockSpec((1, p_pool, ps, hdw), lambda i, j: (j, 0, 0, 0)),
-            pl.BlockSpec((bb, 1), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bb, 1, g, hd), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b + geo.pb, hkv, g, hd), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(lens, pt, qb, kp, vp, vs)
-    return out[:b].reshape(b, 1, hkv * g, hd).astype(q.dtype)
+    lens = jnp.asarray(cache_len, jnp.int32)
+    return packed_attention(q, k_pool, v_pool, v_scale, lens, lens - 1,
+                            window=window, causal=True, block_q=1,
+                            block_b=block_b or 1, interpret=interpret,
+                            page_table=page_table)

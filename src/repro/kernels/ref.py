@@ -6,13 +6,14 @@ with sign(0) := +1 (the paper's Eq. 5 convention, matching binarize_det).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.bitpack import pack_bits, packed_dot, unpack_bits
 
 Array = jax.Array
-NEG_INF = -1e30
 
 
 def sign_pm1(x: Array) -> Array:
@@ -41,6 +42,79 @@ def binary_matmul_fused_ref(a_packed: Array, b_packed: Array, thresh: Array,
     return pack_bits(jnp.where(bits, 1.0, -1.0))
 
 
+# Fixed-point softmax. The sign-dot scores are integers, so the softmax
+# numerators take at most hd + 1 values: exp(-2j/sqrt(hd)) for j = popcount
+# minus the row's smallest popcount. Each is built by a fixed chain of f32
+# multiplies (the same IEEE products on every backend) and held as a 30-bit
+# fixed-point integer in two 15-bit limbs; the row sums and the V sums are
+# then exact integer sums, the same in any order. That is what lets the
+# Pallas kernels (which reduce in tiles, and sum V on the MXU) agree with
+# these oracles bit for bit on every backend.
+LIMB = 15
+
+
+def _softmax_rungs(hd: int) -> list[float]:
+    """exp(-2^k * 2/sqrt(hd)) for every bit k of a popcount gap j <= hd."""
+    return [math.exp(-(2 ** k) * 2.0 / math.sqrt(hd))
+            for k in range(hd.bit_length())]
+
+
+def softmax_weights(pc: Array, valid: Array, hd: int) -> Array:
+    """Fixed-point softmax numerators from xor popcounts.
+
+    pc: (..., T) int32 popcount(xor(q_bits, k_bits_t)) — the sign dot is
+    hd - 2*pc, so the lowest popcount is the top score; valid: bool,
+    broadcastable to pc. Returns (..., T) int32 in [0, 2^30]:
+    2^30 * exp(score_t - max score), masked positions 0."""
+    pc = jnp.where(valid, pc, jnp.int32(hd + 1))
+    j = pc - jnp.min(pc, axis=-1, keepdims=True)
+    e = jnp.ones(j.shape, jnp.float32)
+    for k, rung in enumerate(_softmax_rungs(hd)):
+        e = jnp.where(((j >> k) & 1) != 0, e * jnp.float32(rung), e)
+    w = (e * jnp.float32(1 << (2 * LIMB))).astype(jnp.int32)
+    return jnp.where(valid, w, 0)
+
+
+def limb_sums(w: Array) -> tuple[Array, Array]:
+    """Exact sum of fixed-point weights over the last axis, as (high limb
+    sum, low limb sum) — each fits int32 for T < 2^16."""
+    return (jnp.sum(w >> LIMB, axis=-1, keepdims=True),
+            jnp.sum(w & ((1 << LIMB) - 1), axis=-1, keepdims=True))
+
+
+def exact_bits_dot(w: Array, bits: Array, dims) -> tuple[Array, Array]:
+    """sum_t w_t * bits_t, exact on any matmul unit, as (high limb, low
+    limb) int32 sums. Each 15-bit limb splits into bytes that bf16 holds
+    exactly, and a byte plane's sum stays below 2^24 for T < 65536, so
+    the f32 accumulation is exact in any order. `dims` are
+    lax.dot_general dimension numbers."""
+    b = bits.astype(jnp.float32).astype(jnp.bfloat16)
+
+    def plane(x):
+        return jax.lax.dot_general(
+            x.astype(jnp.float32).astype(jnp.bfloat16), b, dims,
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    def limb(x):
+        return (plane(x >> 8) << 8) + plane(x & 255)
+
+    return limb(w >> LIMB), limb(w & ((1 << LIMB) - 1))
+
+
+def fixed_point_out(sp, l, v_scale: Array) -> Array:
+    """v_scale * sum_t p_t sign(v_t) from the exact limb sums: sp = sums of
+    w_t * bit(v_t) (..., hd), l = sums of w_t (..., 1), both (high, low).
+    sign = 2*bit - 1, so the signed sum is sp - (l - sp) per limb. A row
+    with no valid position (l == 0) gives 0."""
+    def value(hi, lo):
+        return hi.astype(jnp.float32) * jnp.float32(1 << LIMB) + \
+            lo.astype(jnp.float32)
+
+    acc = value(*(s - (t - s) for s, t in zip(sp, l)))
+    den = jnp.maximum(value(*l), jnp.float32(1.0))
+    return v_scale * (acc / den)
+
+
 def decode_attention_packed_ref(q: Array, k_packed: Array, v_packed: Array,
                                 v_scale: Array, cache_len: Array, *,
                                 window: int = 0) -> Array:
@@ -53,45 +127,24 @@ def decode_attention_packed_ref(q: Array, k_packed: Array, v_packed: Array,
         score_t = (hd - 2*popcount(xor(q_bits, k_bits_t))) / sqrt(hd)
         out     = v_scale * softmax(score)_t . sign(v_t)
 
+    with the softmax in 30-bit fixed point (`softmax_weights`).
     q: (B, 1, Hq, hd) float; k_packed/v_packed: (B, T, Hkv, hdw) uint32;
     v_scale: (B, Hkv) float; cache_len: scalar or (B,) valid positions.
-    Masks positions >= cache_len and (window > 0) outside the window.
-    The float op sequence (mask -> max -> exp -> sum -> weighted +-1 V sum
-    -> scale * acc / l) mirrors the kernel exactly — bit-exactness is the
-    tested contract, not just closeness.
+    Masks positions >= cache_len and (window > 0) outside the window —
+    the chunk oracle at S == 1, q_pos == cache_len - 1.
     """
-    b, t, hkv, hdw = k_packed.shape
-    hd = q.shape[-1]
-    g = q.shape[2] // hkv
-    qb = pack_bits(q.reshape(b, hkv, g, hd))                  # (B,Hkv,G,hdw)
-    kb = k_packed.transpose(0, 2, 1, 3)                       # (B,Hkv,T,hdw)
-    vb = v_packed.transpose(0, 2, 1, 3)
-    dots = packed_dot(qb[:, :, :, None, :], kb[:, :, None, :, :], hd)
-    s = dots.astype(jnp.float32) * jnp.float32(1.0 / float(hd) ** 0.5)
-    pos = jnp.arange(t, dtype=jnp.int32)[None, :]             # (1, T)
-    length = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32).reshape(-1),
-                              (b,)).reshape(b, 1)
-    valid = pos < length
-    if window > 0:
-        valid &= pos >= length - window
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)        # (B,Hkv,G,T)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)                                        # masked -> 0.0
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    sgn = unpack_bits(vb, hd)                                 # (B,Hkv,T,hd)
-    acc = jnp.sum(e[..., None] * sgn[:, :, None, :, :], axis=-2)
-    out = v_scale.astype(jnp.float32)[:, :, None, None] * (acc / l)
-    return out.reshape(b, 1, hkv * g, hd).astype(q.dtype)
+    lens = jnp.asarray(cache_len, jnp.int32)
+    return prefill_attention_packed_ref(q, k_packed, v_packed, v_scale,
+                                        lens, lens - 1, window=window)
 
 
 def packed_masked_attention_ref(q: Array, k_packed: Array, v_packed: Array,
                                 v_scale: Array, valid: Array) -> Array:
     """Quantized multi-query attention core with an explicit (B, S, T)
     validity mask — the single definition of the packed-attention op
-    sequence (pack -> popcount dot -> 1/sqrt(hd) -> NEG_INF mask ->
-    max/exp/sum softmax -> +-1 V accumulate under v_scale) that the
-    prefill oracle AND the rg ring-buffer chunk attention both call, so
-    the bit-exactness-critical float ops exist exactly once.
+    sequence (pack -> xor popcount -> fixed-point softmax weights -> exact
+    weighted V-bit sums -> v_scale * acc / l) that the prefill oracle AND
+    the rg ring-buffer chunk attention both call.
 
     q: (B, S, Hq, hd) float; k_packed/v_packed: (B, T, Hkv, hdw) uint32;
     v_scale: (B, Hkv) float. Returns (B, S, Hq, hd) in q.dtype."""
@@ -102,16 +155,15 @@ def packed_masked_attention_ref(q: Array, k_packed: Array, v_packed: Array,
     qb = pack_bits(q.reshape(b, s, hkv, g, hd).transpose(0, 2, 1, 3, 4))
     kb = k_packed.transpose(0, 2, 1, 3)                       # (B,Hkv,T,hdw)
     vb = v_packed.transpose(0, 2, 1, 3)
-    dots = packed_dot(qb[:, :, :, :, None, :],
-                      kb[:, :, None, None, :, :], hd)         # (B,Hkv,S,G,T)
-    sc = dots.astype(jnp.float32) * jnp.float32(1.0 / float(hd) ** 0.5)
-    sc = jnp.where(valid[:, None, :, None, :], sc, NEG_INF)   # (B,Hkv,S,G,T)
-    m = jnp.max(sc, axis=-1, keepdims=True)
-    e = jnp.exp(sc - m)                                       # masked -> 0.0
-    l = jnp.sum(e, axis=-1, keepdims=True)
-    sgn = unpack_bits(vb, hd)                                 # (B,Hkv,T,hd)
-    acc = jnp.sum(e[..., None] * sgn[:, :, None, None, :, :], axis=-2)
-    out = v_scale.astype(jnp.float32)[:, :, None, None, None] * (acc / l)
+    pc = (hd - packed_dot(qb[:, :, :, :, None, :],
+                          kb[:, :, None, None, :, :], hd)) // 2  # (B,Hkv,S,G,T)
+    w = softmax_weights(pc, valid[:, None, :, None, :], hd)
+    bits = unpack_bits(vb, hd) > 0                            # (B,Hkv,T,hd)
+    sp = exact_bits_dot(w.reshape(b, hkv, s * g, t), bits,
+                        (((3,), (2,)), ((0, 1), (0, 1))))     # (B,Hkv,S*G,hd)
+    out = fixed_point_out([x.reshape(b, hkv, s, g, hd) for x in sp],
+                          limb_sums(w),
+                          v_scale.astype(jnp.float32)[:, :, None, None, None])
     return out.transpose(0, 2, 1, 3, 4).reshape(b, s, hkv * g, hd
                                                 ).astype(q.dtype)
 
@@ -153,9 +205,8 @@ def prefill_attention_packed_ref(q: Array, k_packed: Array, v_packed: Array,
     q: (B, S, Hq, hd) float; k_packed/v_packed: (B, T, Hkv, hdw) uint32;
     v_scale: (B, Hkv) float; kv_len, q_pos: scalar or (B,). With S == 1
     and q_pos == kv_len - 1 this is exactly decode_attention_packed_ref.
-    The float op sequence (packed_masked_attention_ref) mirrors the
-    kernel exactly — bit-exactness is the tested contract, not just
-    closeness.
+    The softmax runs in fixed point (packed_masked_attention_ref), so the
+    kernel matches bit for bit — the tested contract, not just closeness.
     """
     b, t = k_packed.shape[0], k_packed.shape[1]
     valid = chunk_valid_mask(b, q.shape[1], t, kv_len, q_pos, window, causal)

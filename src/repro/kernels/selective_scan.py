@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 Array = jax.Array
 
 
@@ -102,7 +100,7 @@ def selective_scan(dt: Array, xi: Array, bmat: Array, cmat: Array,
             jax.ShapeDtypeStruct((b, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, xi, bmat, cmat, a_mat)

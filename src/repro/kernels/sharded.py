@@ -3,7 +3,7 @@
 `pallas_call` is opaque to XLA's auto-sharding: under a plain GSPMD jit a
 sharded operand reaching a Pallas kernel is all-gathered (or the lowering
 fails outright), so the popcount kernels cannot be *partitioned* — but
-they can be *mapped*: under `jax.experimental.shard_map` every device
+they can be *mapped*: under `jax.shard_map` every device
 traces the same kernel over its local shard, grids and block geometry
 derive from the local shape, and the tuning cache is consulted at the
 local shape too (a device owning Hkv/4 heads tunes like a 4x-smaller
@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.bitpack import WORD
@@ -69,9 +68,9 @@ def binary_gemm_tp(a: Array, b_packed: Array, k_true: int, *, mesh,
         return dispatch_binary_gemm(a, bp, k_true, route=route,
                                     interpret=interpret)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(axis, None)),
-                     out_specs=P(None, axis), check_rep=False)(a, b_packed)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(axis, None)),
+                         out_specs=P(None, axis), check_vma=False)(a, b_packed)
 
 
 def binary_gemm_fused_tp(a: Array, b_packed: Array, thresh: Array,
@@ -90,10 +89,10 @@ def binary_gemm_fused_tp(a: Array, b_packed: Array, thresh: Array,
         return dispatch_binary_gemm_fused(a, bp, th, fl, k_true, route=route,
                                           interpret=interpret)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(), P(axis, None), P(axis), P(axis)),
-                     out_specs=P(None, axis),
-                     check_rep=False)(a, b_packed, thresh, flip)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), P(axis, None), P(axis), P(axis)),
+                         out_specs=P(None, axis),
+                         check_vma=False)(a, b_packed, thresh, flip)
 
 
 def _split_heads(q: Array, hkv: int):
@@ -127,10 +126,10 @@ def decode_attention_packed_tp(q: Array, k_packed: Array, v_packed: Array,
         return out.reshape(bl, s, hl, g, hd)
 
     hs = P(None, None, axis, None, None)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(hs, P(None, None, axis, None),
-                              P(None, None, axis, None), P(None, axis), P()),
-                    out_specs=hs, check_rep=False)(
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(hs, P(None, None, axis, None),
+                                  P(None, None, axis, None), P(None, axis), P()),
+                        out_specs=hs, check_vma=False)(
         q5, k_packed, v_packed, v_scale, lens)
     return out.reshape(q.shape)
 
@@ -157,9 +156,9 @@ def decode_attention_packed_paged_tp(q: Array, k_pool: Array, v_pool: Array,
 
     hs = P(None, None, axis, None, None)
     pool = P(None, None, axis, None)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(hs, pool, pool, P(None, axis), P(), P()),
-                    out_specs=hs, check_rep=False)(
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(hs, pool, pool, P(None, axis), P(), P()),
+                        out_specs=hs, check_vma=False)(
         q5, k_pool, v_pool, v_scale, page_table, lens)
     return out.reshape(q.shape)
 
@@ -184,11 +183,11 @@ def prefill_attention_packed_tp(q: Array, k_packed: Array, v_packed: Array,
         return out.reshape(bl, s, hl, g, hd)
 
     hs = P(None, None, axis, None, None)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(hs, P(None, None, axis, None),
-                              P(None, None, axis, None), P(None, axis),
-                              P(), P()),
-                    out_specs=hs, check_rep=False)(
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(hs, P(None, None, axis, None),
+                                  P(None, None, axis, None), P(None, axis),
+                                  P(), P()),
+                        out_specs=hs, check_vma=False)(
         q5, k_packed, v_packed, v_scale, lens, pos)
     return out.reshape(q.shape)
 
@@ -216,8 +215,8 @@ def prefill_attention_packed_paged_tp(q: Array, k_pool: Array, v_pool: Array,
 
     hs = P(None, None, axis, None, None)
     pool = P(None, None, axis, None)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(hs, pool, pool, P(None, axis), P(), P(), P()),
-                    out_specs=hs, check_rep=False)(
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(hs, pool, pool, P(None, axis), P(), P(), P()),
+                        out_specs=hs, check_vma=False)(
         q5, k_pool, v_pool, v_scale, page_table, lens, pos)
     return out.reshape(q.shape)

@@ -84,6 +84,13 @@ FUSED_TILES = [
     dict(bm=256, bn=128, uk=0),
 ]
 DECODE_BLOCK_B = [1, 2, 4, 8]
+# What the TPU heuristic runs on a cache miss (no tuned/tpu.json is
+# committed yet). Every main-path shape compiles with these
+# (tests/test_tpu_compile.py); choosing faster ones is the tuner's job.
+TPU_GEMM_TILE = dict(bm=128, bn=256, bk=128, uk=8)
+TPU_FUSED_TILE = dict(bm=128, bn=4096, uk=8)
+TPU_DECODE_BLOCKS = {"block_b": 1}
+TPU_PREFILL_BLOCKS = {"block_q": 8, "block_b": 1}
 PREFILL_BLOCKS = [dict(block_q=bq, block_b=bb)
                   for bq in (4, 8, 16) for bb in (1, 4)]
 
@@ -182,18 +189,21 @@ def _heuristic(kernel: str, shape: dict[str, int]) -> tuple[str, dict]:
             return ("xla", {}) if m * n * kw <= (1 << 23) else ("float", {})
         return "xla", {}
     if kernel == "binary_gemm":
-        return "vpu", dict(GEMM_TILES[0])
+        return "vpu", dict(TPU_GEMM_TILE)
     if kernel == "binary_gemm_fused":
-        return "vpu", dict(FUSED_TILES[0])
+        return "vpu", dict(TPU_FUSED_TILE)
     if kernel in ("decode_attention", "decode_attention_paged"):
-        return "pallas", {"block_b": 1}
+        return "pallas", dict(TPU_DECODE_BLOCKS)
     if kernel in ("prefill_attention", "prefill_attention_paged"):
-        return "pallas", {"block_q": 8, "block_b": 1}
+        return "pallas", dict(TPU_PREFILL_BLOCKS)
     raise ValueError(f"unknown kernel: {kernel}")
 
 
 # get_route misses, for tooling: maps (kernel, key) -> shape dict.
 misses: dict[tuple[str, str], dict[str, int]] = {}
+# Every resolution get_route has made, (kernel, key) -> route. Dispatch
+# runs at trace time, so this is what the traced programs really call.
+resolved: dict[tuple[str, str], str] = {}
 
 # Active route pins (kernel name -> route), installed by `route_override`.
 # Highest dispatch priority: consulted before the tuned cache.
@@ -240,6 +250,12 @@ def get_route(kernel: str, **shape: int) -> tuple[str, dict]:
     pin wins; then a cache hit; otherwise the backend heuristic (or, with
     REPRO_AUTOTUNE=1 outside a trace, tune the missing bucket now and
     persist it)."""
+    route, params = _resolve(kernel, shape)
+    resolved[(kernel, bucket_key(shape))] = route
+    return route, params
+
+
+def _resolve(kernel: str, shape: dict[str, int]) -> tuple[str, dict]:
     if kernel in _ROUTE_OVERRIDE:
         return _ROUTE_OVERRIDE[kernel], {}
     key = bucket_key(shape)
@@ -247,17 +263,11 @@ def get_route(kernel: str, **shape: int) -> tuple[str, dict]:
     if entry is not None:
         return entry["route"], dict(entry.get("params", {}))
     misses[(kernel, key)] = dict(shape)
-    if os.environ.get("REPRO_AUTOTUNE") == "1" and _trace_clean():
+    if os.environ.get("REPRO_AUTOTUNE") == "1" and \
+            jax.core.trace_ctx.is_top_level():
         entry = tune_bucket(kernel, bucket(shape))
         return entry["route"], dict(entry.get("params", {}))
     return _heuristic(kernel, shape)
-
-
-def _trace_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:   # pragma: no cover - jax version drift
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -527,6 +537,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--show", action="store_true",
                     help="print the tuned decision table")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.tune:
         return _cli_tune(args.force)
     if args.check:

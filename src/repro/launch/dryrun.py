@@ -128,8 +128,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0 - t_lower
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax wraps the dict in a list
-        cost = cost[0] if cost else {}
     mem = _mem_dict(compiled.memory_analysis())
     hlo = compiled.as_text()
     from repro.roofline.hlo import analyze

@@ -17,17 +17,25 @@ import jax
 import numpy as np
 
 
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """jax.make_mesh with Auto axes: the sharding hints
+    (`with_sharding_constraint`) and gathers the models use are written
+    for GSPMD's automatic propagation, not for Explicit sharding types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def parse_mesh(spec: str) -> tuple[int, int]:

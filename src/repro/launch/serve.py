@@ -86,6 +86,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_config
     from repro.configs.smoke import smoke_config
     from repro.models.api import get_model

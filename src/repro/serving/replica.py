@@ -51,6 +51,8 @@ import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core.packed import params_frozen
+from repro.models.api import get_model
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.faults import FaultPlan, ReplicaDead
 
@@ -69,9 +71,12 @@ class ReplicaServer:
 
     `devices`: one jax device per replica (default: every visible
     device). Engine kwargs (`freeze`, `kv_bits`, `slots`, `prefill_chunk`,
-    `page_size`, ...) apply to every replica. Each replica holds its own
-    copy of `params` (device_put at construction; freezing packs per
-    replica), its own KV cache/pool, and its own prefix tree — prefix
+    `page_size`, ...) apply to every replica. With `freeze`, fp masters
+    are packed once, where they already live, and only the packed tree is
+    placed on the replicas' devices (masters are ~32x larger: a full-width
+    copy per device would not fit). Each replica holds its own copy of the
+    params (device_put at construction), its own KV cache/pool, and its
+    own prefix tree — prefix
     sharing stays per-replica, which is why round-robin (not
     least-loaded) assignment is the default: equal interleaving keeps
     repeated prefixes landing on every replica.
@@ -94,6 +99,8 @@ class ReplicaServer:
         self.last_errors: dict[int, str] = {}
         self.failovers = 0
         self.engines: list[ServingEngine] = []
+        if engine_kw.get("freeze") and not params_frozen(params):
+            params = get_model(cfg).freeze(params)
         for dev in self.devices:
             with jax.default_device(dev):
                 self.engines.append(

@@ -101,7 +101,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -403,11 +402,11 @@ class Scheduler:
                 return state, cache
 
             pspecs = jax.tree.map(lambda _: P(), self.params)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=self._mesh,
                 in_specs=(pspecs, self._state_specs, self._cache_specs),
                 out_specs=(self._state_specs, self._cache_specs),
-                check_rep=False), donate_argnums=(1, 2))
+                check_vma=False), donate_argnums=(1, 2))
             self._burst_jits[(drain, max_steps)] = fn
         return fn
 

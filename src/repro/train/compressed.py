@@ -84,15 +84,14 @@ def make_compressed_train_step(model: Model, opt: Optimizer, mesh,
 
     @functools.partial(jax.jit)
     def step(params, opt_state, ef_err, batch):
-        from jax.experimental.shard_map import shard_map
-        sm = shard_map(
+        sm = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(specs_like(params, rep), specs_like(opt_state, rep),
                       specs_like(ef_err, P(axis)),
                       specs_like(batch, P(axis))),
             out_specs=(specs_like(params, rep), specs_like(opt_state, rep),
                        specs_like(ef_err, P(axis)), {"loss": rep}),
-            check_rep=False)
+            check_vma=False)
         return sm(params, opt_state, ef_err, batch)
 
     return step

@@ -210,3 +210,33 @@ def test_resident_cache_bytes_shrink_at_least_16x():
     # and the packed K/V words are exactly 1 bit per float element
     hdw = packed_width(cfg.head_dim)
     assert p["packed"] * cfg.head_dim == f["total"] * hdw
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("hd", [20, 32, 64, 128])
+def test_fixed_point_softmax_weights(hd):
+    """The integer softmax numerators are 2^30 * exp(score - max) to f32
+    precision at every popcount gap, and masked positions weigh nothing."""
+    j = jnp.arange(hd + 1, dtype=jnp.int32)[None]
+    valid = jnp.ones_like(j, bool).at[0, -1].set(False)
+    got = np.asarray(ref.softmax_weights(j + 3, valid, hd))[0]
+    want = 2.0 ** 30 * np.exp(-2.0 * np.arange(hd + 1) / np.sqrt(hd))
+    assert got[0] == 2 ** 30 and got[-1] == 0
+    np.testing.assert_allclose(got[:-1], want[:-1], rtol=1e-6, atol=1)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("t", [1, 7, 300])
+def test_exact_bits_dot_is_integer_exact(t):
+    """The byte-plane bf16 matmuls return the exact weighted bit sums, limb
+    by limb, at the largest weights."""
+    rng = np.random.default_rng(t)
+    w = rng.integers(0, 2 ** 30 + 1, (3, 5, t)).astype(np.int32)
+    w[..., 0] = 2 ** 30
+    bits = rng.integers(0, 2, (3, 9, t)).astype(np.int32)
+    hi, lo = ref.exact_bits_dot(jnp.asarray(w), jnp.asarray(bits),
+                                (((2,), (2,)), ((0,), (0,))))
+    want = np.einsum("brt,bdt->brd", w.astype(np.int64), bits)
+    np.testing.assert_array_equal(
+        np.asarray(hi).astype(np.int64) * 2 ** ref.LIMB + np.asarray(lo),
+        want)

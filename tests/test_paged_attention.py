@@ -191,3 +191,18 @@ def test_paged_float_chunk_matches_contiguous():
     got = np.asarray(chunk_attention_paged(q, k_pool, v_pool, pt,
                                            kv_len, q_pos))
     np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.kernels
+def test_tpu_path_rejects_unaligned_page_size():
+    """Compiled for the chip, a page is a lane slice of the kernel's VMEM
+    panel: a page size that is not a multiple of 128 is refused up front
+    with the reason, not deep inside the TPU compiler."""
+    b, t, ps, hkv, hd = 2, 16, 8, 2, 32
+    q = jnp.zeros((b, 1, hkv, hd))
+    pool = jnp.zeros((b * t // ps, ps, hkv, 1), jnp.uint32)
+    pt = jnp.arange(b * t // ps, dtype=jnp.int32).reshape(b, t // ps)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        decode_attention_packed_paged(q, pool, pool, jnp.ones((b, hkv)), pt,
+                                      jnp.full((b,), t), route="pallas",
+                                      interpret=False)
