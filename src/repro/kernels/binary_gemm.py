@@ -140,7 +140,7 @@ def _vpu_call(a: Array, b_packed: Array, k_true: int, geo, *,
         scratch_shapes=_word_scratch(geo.bm, geo.bn, geo.bk),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="binary_gemm_vpu",
     )(a, b_packed)
 
 
@@ -288,7 +288,7 @@ def binary_gemm_vpu_packed_io(a: Array, b_packed: Array, thresh: Array,
         scratch_shapes=_word_scratch(bm, bn, kw),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=interpret, name="binary_gemm_fused",
     )(a, b_packed, thresh[None, :], flip[None, :])
     return out[:m, :(n + WORD - 1) // WORD]
 
@@ -338,7 +338,7 @@ def binary_gemm_mxu(x: Array, w: Array, *, bm: int = 128, bn: int = 128,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], w.shape[1]), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="binary_gemm_mxu",
     )(x, w)
     if geo.pk:
         out = out - jnp.float32(geo.pk)  # remove the +1*+1 pad contributions

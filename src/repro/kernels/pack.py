@@ -77,6 +77,6 @@ def pack_bits_kernel(x: Array, *, bm: int = 256, bkw: int = 8,
                                        jnp.uint32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=interpret, name="pack_bits",
     )(x)
     return out[:m, :kw]

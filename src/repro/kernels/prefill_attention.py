@@ -219,6 +219,7 @@ def packed_attention(q: Array, k: Array, v: Array, v_scale: Array,
             in_specs=[small, small, q_spec, panel, panel],
             out_specs=out_spec, out_shape=out_shape,
             compiler_params=params, interpret=interpret,
+            name="packed_attention",
         )(lens, qpos, qb, kt, vt)
     else:
         p_pool, ps = k.shape[0], k.shape[1]
@@ -244,7 +245,7 @@ def packed_attention(q: Array, k: Array, v: Array, v_scale: Array,
                                            jnp.uint32)] * 2
                 + [pltpu.SemaphoreType.DMA(())]),
             out_shape=out_shape, compiler_params=params,
-            interpret=interpret,
+            interpret=interpret, name="packed_attention_paged",
         )(pt.reshape(-1), lens, qpos, qb, kp, vp)
     out = ref.fixed_point_out(
         sp[:, :b].reshape(2, b, hkv, s_pad, g, hd),

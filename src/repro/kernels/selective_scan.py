@@ -102,6 +102,6 @@ def selective_scan(dt: Array, xi: Array, bmat: Array, cmat: Array,
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="selective_scan",
     )(dt, xi, bmat, cmat, a_mat)
     return y[:, :t], h_fin

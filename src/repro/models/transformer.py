@@ -484,50 +484,48 @@ def _decode_self_block(bp, h, kc, vc, cfg, mode, pos, window, v_scale=None,
     every page a request can reach at admission, so active rows always
     hit a real page), and attention walks the table in the paged kernels.
     Inactive rows write at the pool-size sentinel and drop, exactly like
-    the contiguous t_max convention."""
+    the contiguous t_max convention. The parts run in named scopes
+    (`qkv`, `kv_write`, `attention`, `ffn`) that a device trace shows."""
     b = h.shape[0]
-    xn = _norm(bp["ln1"], h, cfg)
-    q, k_new, v_new = _qkv(bp["attn"], xn, cfg, mode, False, None)
-    if cfg.pos == "rope":
-        positions = pos[:, None]                               # (B, 1)
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        xn = _norm(bp["ln1"], h, cfg)
+        q, k_new, v_new = _qkv(bp["attn"], xn, cfg, mode, False, None)
+        if cfg.pos == "rope":
+            positions = pos[:, None]                           # (B, 1)
+            q = rope(q, positions, cfg.rope_theta)
+            k_new = rope(k_new, positions, cfg.rope_theta)
     rows = jnp.arange(b)
-    if pt is not None:
-        p_pool, ps = kc.shape[0], kc.shape[1]
-        posc = jnp.maximum(pos, 0)
-        pidx = jnp.clip(posc // ps, 0, pt.shape[1] - 1)
-        wpage = jnp.where(pos >= 0, pt[rows, pidx], p_pool)    # inactive: drop
-        wrow = posc % ps
+    with jax.named_scope("kv_write"):
+        if pt is not None:
+            p_pool, ps = kc.shape[0], kc.shape[1]
+            posc = jnp.maximum(pos, 0)
+            pidx = jnp.clip(posc // ps, 0, pt.shape[1] - 1)
+            at = (jnp.where(pos >= 0, pt[rows, pidx], p_pool), posc % ps)
+        else:
+            at = (rows, jnp.where(pos >= 0, pos, kc.shape[1]))
+        # inactive rows write at the pool size / t_max sentinel: dropped
         if cfg.kv_bits == 1:
-            kc = kc.at[wpage, wrow].set(pack_bits(k_new[:, 0]), mode="drop")
-            vc = vc.at[wpage, wrow].set(pack_bits(v_new[:, 0]), mode="drop")
+            kc = kc.at[at].set(pack_bits(k_new[:, 0]), mode="drop")
+            vc = vc.at[at].set(pack_bits(v_new[:, 0]), mode="drop")
+        else:
+            kc = kc.at[at].set(k_new[:, 0].astype(kc.dtype), mode="drop")
+            vc = vc.at[at].set(v_new[:, 0].astype(vc.dtype), mode="drop")
+    with jax.named_scope("attention"):
+        if pt is not None and cfg.kv_bits == 1:
             out = decode_attention_packed_paged(q, kc, vc, v_scale, pt,
                                                 pos + 1, window=window)
-        else:
-            kc = kc.at[wpage, wrow].set(k_new[:, 0].astype(kc.dtype),
-                                        mode="drop")
-            vc = vc.at[wpage, wrow].set(v_new[:, 0].astype(vc.dtype),
-                                        mode="drop")
+        elif pt is not None:
             out = decode_attention_paged(q, kc, vc, pt, pos + 1,
                                          window=window)
-    else:
-        t_max = kc.shape[1]
-        wpos = jnp.where(pos >= 0, pos, t_max)                 # inactive: drop
-        if cfg.kv_bits == 1:
-            kc = kc.at[rows, wpos].set(pack_bits(k_new[:, 0]), mode="drop")
-            vc = vc.at[rows, wpos].set(pack_bits(v_new[:, 0]), mode="drop")
+        elif cfg.kv_bits == 1:
             out = decode_attention_packed(q, kc, vc, v_scale, pos + 1,
                                           window=window)
         else:
-            kc = kc.at[rows, wpos].set(k_new[:, 0].astype(kc.dtype),
-                                       mode="drop")
-            vc = vc.at[rows, wpos].set(v_new[:, 0].astype(vc.dtype),
-                                       mode="drop")
             out = decode_attention(q, kc, vc, pos + 1, window=window)
-    out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    h = h + qmatmul(out, bp["attn"]["wo"], mode)
-    h, _ = ffn_sublayer(bp, h, cfg, mode, train=False, key=None)
+        out = out.reshape(b, 1, cfg.n_heads * cfg.head_dim)
+        h = h + qmatmul(out, bp["attn"]["wo"], mode)
+    with jax.named_scope("ffn"):
+        h, _ = ffn_sublayer(bp, h, cfg, mode, train=False, key=None)
     return h, kc, vc
 
 
@@ -600,7 +598,8 @@ def transformer_decode(params: dict, cfg: ModelConfig, token: Array,
         h, (ks, vs) = jax.lax.scan(block_body, h, block_xs)
         new_cache = dict(cache, k=ks, v=vs)
 
-    logits = _head(params, cfg, h)[:, 0]
+    with jax.named_scope("head"):
+        logits = _head(params, cfg, h)[:, 0]
     return logits, new_cache
 
 
@@ -625,53 +624,51 @@ def _chunk_self_block(bp, h, kc, vc, vs, cfg, mode, positions, widx, kv_len,
     sentinel and drop), and attention walks the table in the paged
     kernels. The running V-scale update is layout-independent and shared
     verbatim — which is what keeps paged prefill == contiguous prefill
-    bit-exact."""
+    bit-exact. The parts run in the named scopes of `_decode_self_block`."""
     c = h.shape[1]
-    xn = _norm(bp["ln1"], h, cfg)
-    q, k_new, v_new = _qkv(bp["attn"], xn, cfg, mode, False, None)
-    if cfg.pos == "rope":
-        q = rope(q, positions, cfg.rope_theta)
-        k_new = rope(k_new, positions, cfg.rope_theta)
-    if pt_row is not None:
-        p_pool, ps = kc.shape[0], kc.shape[1]
-        pidx = jnp.clip(positions // ps, 0, pt_row.shape[0] - 1)
-        wpage = jnp.where(jnp.arange(c) < n_valid, pt_row[pidx], p_pool)
-        wrow = positions % ps
+    with jax.named_scope("qkv"):
+        xn = _norm(bp["ln1"], h, cfg)
+        q, k_new, v_new = _qkv(bp["attn"], xn, cfg, mode, False, None)
+        if cfg.pos == "rope":
+            q = rope(q, positions, cfg.rope_theta)
+            k_new = rope(k_new, positions, cfg.rope_theta)
+    with jax.named_scope("kv_write"):
+        if pt_row is not None:
+            p_pool, ps = kc.shape[0], kc.shape[1]
+            pidx = jnp.clip(positions // ps, 0, pt_row.shape[0] - 1)
+            at = (jnp.where(jnp.arange(c) < n_valid, pt_row[pidx], p_pool),
+                  positions % ps)
+        else:
+            at = (0, widx)
         if cfg.kv_bits == 1:
-            kc = kc.at[wpage, wrow].set(pack_bits(k_new[0]), mode="drop")
-            vc = vc.at[wpage, wrow].set(pack_bits(v_new[0]), mode="drop")
+            kc = kc.at[at].set(pack_bits(k_new[0]), mode="drop")
+            vc = vc.at[at].set(pack_bits(v_new[0]), mode="drop")
+            # running mean |v| over (positions so far, head_dim): equals
+            # the whole-prompt v_cache_scale once the last chunk lands
             absm = jnp.mean(jnp.abs(v_new[0].astype(jnp.float32)), axis=-1)
             msk = (jnp.arange(c) < n_valid)[:, None]
-            vs = (vs * pos.astype(jnp.float32) +
-                  jnp.sum(absm * msk, axis=0)[None]) / \
+            vs = (vs * pos.astype(jnp.float32)
+                  + jnp.sum(absm * msk, axis=0)[None]) / \
                 kv_len.astype(jnp.float32)
+        else:
+            kc = kc.at[at].set(k_new[0].astype(kc.dtype), mode="drop")
+            vc = vc.at[at].set(v_new[0].astype(vc.dtype), mode="drop")
+    with jax.named_scope("attention"):
+        if pt_row is not None and cfg.kv_bits == 1:
             out = prefill_attention_packed_paged(q, kc, vc, vs, pt_row[None],
                                                  kv_len, pos, window=window)
-        else:
-            kc = kc.at[wpage, wrow].set(k_new[0].astype(kc.dtype),
-                                        mode="drop")
-            vc = vc.at[wpage, wrow].set(v_new[0].astype(vc.dtype),
-                                        mode="drop")
+        elif pt_row is not None:
             out = chunk_attention_paged(q, kc, vc, pt_row[None], kv_len, pos,
                                         window=window)
-    elif cfg.kv_bits == 1:
-        kc = kc.at[0, widx].set(pack_bits(k_new[0]), mode="drop")
-        vc = vc.at[0, widx].set(pack_bits(v_new[0]), mode="drop")
-        # running mean |v| over (positions so far, head_dim): equals the
-        # whole-prompt v_cache_scale once the last chunk lands
-        absm = jnp.mean(jnp.abs(v_new[0].astype(jnp.float32)), axis=-1)
-        msk = (jnp.arange(c) < n_valid)[:, None]
-        vs = (vs * pos.astype(jnp.float32)
-              + jnp.sum(absm * msk, axis=0)[None]) / kv_len.astype(jnp.float32)
-        out = prefill_attention_packed(q, kc, vc, vs, kv_len, pos,
-                                       window=window)
-    else:
-        kc = kc.at[0, widx].set(k_new[0].astype(kc.dtype), mode="drop")
-        vc = vc.at[0, widx].set(v_new[0].astype(vc.dtype), mode="drop")
-        out = chunk_attention(q, kc, vc, kv_len, pos, window=window)
-    out = out.reshape(1, c, cfg.n_heads * cfg.head_dim)
-    h = h + qmatmul(out, bp["attn"]["wo"], mode)
-    h, _ = ffn_sublayer(bp, h, cfg, mode, train=False, key=None)
+        elif cfg.kv_bits == 1:
+            out = prefill_attention_packed(q, kc, vc, vs, kv_len, pos,
+                                           window=window)
+        else:
+            out = chunk_attention(q, kc, vc, kv_len, pos, window=window)
+        out = out.reshape(1, c, cfg.n_heads * cfg.head_dim)
+        h = h + qmatmul(out, bp["attn"]["wo"], mode)
+    with jax.named_scope("ffn"):
+        h, _ = ffn_sublayer(bp, h, cfg, mode, train=False, key=None)
     return h, kc, vc, vs
 
 
@@ -824,4 +821,5 @@ def transformer_prefill_chunk(params: dict, cfg: ModelConfig, tokens: Array,
             new_cache["v_scale"] = dput(cache["v_scale"], st[2], 1)
 
     hl = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
-    return _head(params, cfg, hl)[:, 0], new_cache
+    with jax.named_scope("head"):
+        return _head(params, cfg, hl)[:, 0], new_cache

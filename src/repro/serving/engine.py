@@ -45,8 +45,6 @@ slot's table and prefills only the unseen suffix.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,8 +92,6 @@ class ServingEngine:
         if freeze:
             self.freeze()
         self._build_step_fns()
-        self.stats = {"prefill_tokens": 0, "decode_steps": 0,
-                      "prefill_s": 0.0, "decode_s": 0.0}
 
     def _build_step_fns(self) -> None:
         self._decode = jax.jit(self.model.decode, donate_argnums=(2,))
@@ -327,23 +323,15 @@ class ServingEngine:
         if key is None and any(r.temperature > 0 for r in requests):
             key = self._next_key()
 
-        t0 = time.time()
         logits, cache = self._prefill(self.params, tokens)
-        logits.block_until_ready()
-        self.stats["prefill_s"] += time.time() - t0
-        self.stats["prefill_tokens"] += int(tokens.size)
-
         cur = self._select(logits, requests, key, 0)
         steps = [cur]
-        t0 = time.time()
         for i in range(max_new - 1):
             logits, cache = self._decode(self.params, cur, cache,
                                          jnp.int32(s + i))
             cur = self._select(logits, requests, key, i + 1)
             steps.append(cur)
-            self.stats["decode_steps"] += 1
         out = jax.device_get(jnp.stack(steps, axis=1))   # ONE transfer
-        self.stats["decode_s"] += time.time() - t0
         # the batch decodes max(max_new_tokens) steps together; honor each
         # request's own budget in what we hand back
         return [out[j, :r.max_new_tokens].astype(np.int32)

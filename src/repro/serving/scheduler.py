@@ -36,6 +36,17 @@ event, not once per token. A freed slot is recycled to the next queued
 request immediately. All wall-time stats sync the device before reading
 the clock (`prefill_s` / `decode_s` measure compute, not dispatch).
 
+Tracing: each part of a round runs inside a host span
+(`jax.profiler.TraceAnnotation`, inert unless a profiler trace is
+active), so a trace puts the scheduler's work on the device's clock:
+`serve.poll` holds `serve.admit` (one per popped request), `serve.chunk`
+(one prefill call), `serve.burst` (one decode burst), `serve.itl` and
+`serve.harvest`; the spans of one request carry its `rid`. `stats` counts
+the same work on the host (rounds and their seconds, prefill calls,
+decode tokens and the keys they attend) from values the scheduler
+already holds, with no extra device sync. Every timer reads
+`time.perf_counter`; only differences of its readings are used.
+
 Ordering guarantees: completions are delivered in completion order;
 requests that finish in the same burst are delivered in submission
 order. Greedy outputs are batch-composition-independent — bit-identical
@@ -144,6 +155,10 @@ class Completion:
     # seconds, submit -> first token sampled, wall clock: admission compute
     # PLUS every stall behind other slots' chunks and decode bursts
     ttft_wall: float = 0.0
+    # seconds, submit -> start of the request's first prefill call: the
+    # wait for a slot and behind other requests' admissions, before any
+    # compute of its own (0 for a request resolved without one)
+    queue_s: float = 0.0
     # prompt tokens served from the prefix cache (skipped prefill)
     cached_tokens: int = 0
     # inter-token intervals (seconds) for decode tokens, at burst
@@ -271,6 +286,7 @@ class Scheduler:
         self._submit_time: dict[int, float] = {}    # pending/running only
         self._ttft: dict[int, float] = {}
         self._ttft_wall: dict[int, float] = {}
+        self._queue_s: dict[int, float] = {}
         self._req_prefill_s: dict[int, float] = {}  # own-admission compute
         self._cached_tokens: dict[int, int] = {}
         self._vs_snaps: dict[int, dict[int, Any]] = {}
@@ -278,12 +294,22 @@ class Scheduler:
         self._slot_last_tok: dict[int, float] = {}
         self._prev_out_len = np.zeros((n_slots,), np.int64)
         self._prefill_shapes: set = set()
+        # work counts: `prefill_tokens` are the valid prompt rows of the
+        # `chunks` prefill calls (a whole-prompt admission is one call);
+        # `chunk_keys` sums the keys their queries attend (causal) and
+        # `chunk_kv` the key positions each call reads; `decode_tokens`
+        # are tokens sampled by decode steps, `decode_keys` the keys they
+        # attend (token j of an L-token prompt attends L + j positions);
+        # `first_tokens` count admissions finished
         self.stats = {"prefill_tokens": 0, "prefill_s": 0.0, "bursts": 0,
                       "decode_s": 0.0, "tokens_out": 0, "completed": 0,
                       "max_admit_stall_tokens": 0,
                       "prefill_tokens_saved": 0, "prefix_hits": 0,
                       "shed": 0, "errors": 0, "rejected": 0,
-                      "burst_retries": 0, "invariant_violations": 0}
+                      "burst_retries": 0, "invariant_violations": 0,
+                      "polls": 0, "poll_s": 0.0, "chunks": 0,
+                      "chunk_keys": 0, "chunk_kv": 0, "decode_tokens": 0,
+                      "decode_keys": 0, "first_tokens": 0}
 
         self._cache = model.init_cache(n_slots, max_len, **cache_kw)
         self._state = {
@@ -498,9 +524,10 @@ class Scheduler:
             act = st["active"]
             pos = jnp.where(act, st["pos"], -1)
             logits, cache = self.model.decode(params, st["cur"], cache, pos)
-            keys = step_keys(st["rkey"], st["out_len"])
-            nxt = sample_tokens(logits, keys, st["temp"])
-            nxt = jnp.where(act, nxt, st["cur"])
+            with jax.named_scope("sample"):
+                keys = step_keys(st["rkey"], st["out_len"])
+                nxt = sample_tokens(logits, keys, st["temp"])
+                nxt = jnp.where(act, nxt, st["cur"])
             # per-row poison isolation: a row whose logits went non-finite
             # finishes NOW with err set; neighbors never see its values
             bad = act & ~jnp.isfinite(logits).all(axis=-1)
@@ -518,6 +545,17 @@ class Scheduler:
         return jax.lax.while_loop(cond, body, (state, cache))
 
     # -- host-side loop -----------------------------------------------------
+    @contextlib.contextmanager
+    def _span(self, name: str, stat: str | None = None, **args):
+        """A host span on the profiler's clock, carrying `args`; yields
+        its start time. With `stat`, the span's seconds add to that
+        counter of `stats` when its body returns."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(name, **args):
+            yield t0
+            if stat is not None:
+                self.stats[stat] += time.perf_counter() - t0
+
     def reseed(self, key: Array) -> None:
         """Set the base key for requests submitted from now on. Keys fold
         the request's index *since this reseed*, so replaying the same
@@ -585,7 +623,7 @@ class Scheduler:
         rid = self._next_rid
         self._next_rid += 1
         self._queue.append((rid, dataclasses.replace(req, prompt=prompt)))
-        self._submit_time[rid] = time.time()
+        self._submit_time[rid] = time.perf_counter()
         return rid
 
     @property
@@ -602,7 +640,7 @@ class Scheduler:
         return len(self._prefill_shapes)
 
     def _note_first_token(self, slot: int, rid: int) -> None:
-        now = time.time()
+        now = time.perf_counter()
         wall = now - self._submit_time[rid]
         self._ttft_wall[rid] = wall
         # ttft = the request's OWN admission compute (device-synced sum of
@@ -611,38 +649,55 @@ class Scheduler:
         self._ttft[rid] = self._req_prefill_s.pop(rid, wall)
         self._slot_last_tok[slot] = now
         self._prev_out_len[slot] = 1
+        self.stats["first_tokens"] += 1
+
+    @contextlib.contextmanager
+    def _prefill_span(self, rid: int, lo: int, hi: int, **args):
+        """`serve.chunk` around one prefill call over prompt positions
+        [lo, hi), whose body ends synced with the device: its seconds go
+        to `prefill_s` and to the request's own admission compute, its
+        work to the prefill counters. The request's first call ends its
+        queue wait."""
+        before = self.stats["prefill_s"]
+        with self._span("serve.chunk", "prefill_s", rid=rid, rows=hi - lo,
+                        **args) as t0:
+            if rid not in self._req_prefill_s:
+                self._queue_s[rid] = t0 - self._submit_time[rid]
+            yield
+        self._req_prefill_s[rid] = self._req_prefill_s.get(rid, 0.0) + \
+            self.stats["prefill_s"] - before
+        self.stats["chunks"] += 1
+        self.stats["prefill_tokens"] += hi - lo
+        self.stats["chunk_keys"] += (hi * (hi + 1) - lo * (lo + 1)) // 2
+        self.stats["chunk_kv"] += hi
 
     def _admit(self, slot: int, rid: int, req: Request,
                poison: float = 0.0) -> None:
         if self._running:   # in-flight slots stall for this whole prefill
             self.stats["max_admit_stall_tokens"] = max(
                 self.stats["max_admit_stall_tokens"], int(req.prompt.size))
-        t0 = time.time()
-        tokens = jax.device_put(req.prompt[None])
-        rkey = request_key(self._base_key, rid - self._key_rid0)
-        eos = -1 if req.eos_id is None else int(req.eos_id)
-        if self.cfg.family == "vlm":
-            assert req.img_emb is not None, "vlm request needs img_emb"
-            img = jax.device_put(np.asarray(req.img_emb)[None])
-            with self._admit_ctx():
-                self._state, self._cache = self._admit_img_jit(
-                    self.params, self._state, self._cache, tokens, img, slot,
-                    rkey, req.max_new_tokens, float(req.temperature), eos,
-                    poison)
-        else:
-            with self._admit_ctx():
-                self._state, self._cache = self._admit_jit(
-                    self.params, self._state, self._cache, tokens, slot,
-                    rkey, req.max_new_tokens, float(req.temperature), eos,
-                    poison)
-        jax.block_until_ready(self._state["done"])   # honest prefill_s
-        dt = time.time() - t0
-        self.stats["prefill_s"] += dt
-        self._req_prefill_s[rid] = dt
+        with self._prefill_span(rid, 0, int(req.prompt.size)):
+            tokens = jax.device_put(req.prompt[None])
+            rkey = request_key(self._base_key, rid - self._key_rid0)
+            eos = -1 if req.eos_id is None else int(req.eos_id)
+            if self.cfg.family == "vlm":
+                assert req.img_emb is not None, "vlm request needs img_emb"
+                img = jax.device_put(np.asarray(req.img_emb)[None])
+                with self._admit_ctx():
+                    self._state, self._cache = self._admit_img_jit(
+                        self.params, self._state, self._cache, tokens, img,
+                        slot, rkey, req.max_new_tokens,
+                        float(req.temperature), eos, poison)
+            else:
+                with self._admit_ctx():
+                    self._state, self._cache = self._admit_jit(
+                        self.params, self._state, self._cache, tokens, slot,
+                        rkey, req.max_new_tokens, float(req.temperature),
+                        eos, poison)
+            jax.block_until_ready(self._state["done"])   # honest prefill_s
         self._prefill_shapes.add(("whole", int(req.prompt.size)))
         self._running[slot] = _Running(rid, int(req.prompt.size),
                                        req.max_new_tokens)
-        self.stats["prefill_tokens"] += int(req.prompt.size)
         self._note_first_token(slot, rid)
 
     # -- chunked admission --------------------------------------------------
@@ -790,33 +845,29 @@ class Scheduler:
         if self._running:   # running slots wait only for THIS chunk
             self.stats["max_admit_stall_tokens"] = max(
                 self.stats["max_admit_stall_tokens"], n_valid)
-        t0 = time.time()
-        chunk = np.zeros((1, c), np.int32)
-        chunk[0, :n_valid] = req.prompt[lo:lo + n_valid]
-        tokens = jax.device_put(chunk)
-        img_args = ()
-        if with_img:
-            assert req.img_emb is not None, "vlm request needs img_emb"
-            img_args = (jax.device_put(np.asarray(req.img_emb)[None]),)
-        if final:
-            rkey = request_key(self._base_key, adm.rid - self._key_rid0)
-            eos = -1 if req.eos_id is None else int(req.eos_id)
-            with self._admit_ctx():
-                self._state, self._cache = self._chunk_call(True, with_img)(
-                    self.params, self._state, self._cache, tokens, slot, lo,
-                    n_valid, rkey, req.max_new_tokens, float(req.temperature),
-                    eos, adm.poison, *img_args)
-        else:
-            with self._admit_ctx():
-                self._cache = self._chunk_call(False, with_img)(
-                    self.params, self._cache, tokens, slot, lo, n_valid,
-                    *img_args)
-        jax.block_until_ready(self._cache)           # honest prefill_s
-        dt = time.time() - t0
-        self.stats["prefill_s"] += dt
-        self._req_prefill_s[adm.rid] = \
-            self._req_prefill_s.get(adm.rid, 0.0) + dt
-        self.stats["prefill_tokens"] += n_valid
+        with self._prefill_span(adm.rid, lo, lo + n_valid, chunk=adm.next):
+            chunk = np.zeros((1, c), np.int32)
+            chunk[0, :n_valid] = req.prompt[lo:lo + n_valid]
+            tokens = jax.device_put(chunk)
+            img_args = ()
+            if with_img:
+                assert req.img_emb is not None, "vlm request needs img_emb"
+                img_args = (jax.device_put(np.asarray(req.img_emb)[None]),)
+            if final:
+                rkey = request_key(self._base_key, adm.rid - self._key_rid0)
+                eos = -1 if req.eos_id is None else int(req.eos_id)
+                with self._admit_ctx():
+                    self._state, self._cache = self._chunk_call(
+                        True, with_img)(
+                        self.params, self._state, self._cache, tokens, slot,
+                        lo, n_valid, rkey, req.max_new_tokens,
+                        float(req.temperature), eos, adm.poison, *img_args)
+            else:
+                with self._admit_ctx():
+                    self._cache = self._chunk_call(False, with_img)(
+                        self.params, self._cache, tokens, slot, lo, n_valid,
+                        *img_args)
+            jax.block_until_ready(self._cache)       # honest prefill_s
         self._prefill_shapes.add(("chunk", c, final, with_img))
         adm.next += 1
         end = lo + n_valid
@@ -840,19 +891,24 @@ class Scheduler:
         split the burst duration evenly, and the time a slot sat stalled
         BEFORE the burst (e.g. behind another request's admission) lands
         on its first token's interval — so a head-of-line-blocking prefill
-        shows up as one large interval instead of being amortized away."""
-        now = time.time()
+        shows up as one large interval instead of being amortized away.
+        The same counts feed `decode_tokens` and `decode_keys`."""
+        now = time.perf_counter()
         dur = now - t_start
         out_len = np.asarray(jax.device_get(self._state["out_len"]))
         for slot, info in self._running.items():
-            n = int(out_len[slot] - self._prev_out_len[slot])
+            first, last = int(self._prev_out_len[slot]), int(out_len[slot])
+            n = last - first
             if n > 0:
                 per = dur / n
                 stall = t_start - self._slot_last_tok.get(slot, t_start)
                 self._itl.setdefault(info.rid, []).extend(
                     [stall + per] + [per] * (n - 1))
                 self._slot_last_tok[slot] = now
-            self._prev_out_len[slot] = out_len[slot]
+                self.stats["decode_tokens"] += n
+                self.stats["decode_keys"] += \
+                    n * info.prompt_len + (first + last - 1) * n // 2
+            self._prev_out_len[slot] = last
 
     def _harvest(self) -> list[Completion]:
         """One explicit host transfer of the done/out state; frees and
@@ -862,42 +918,45 @@ class Scheduler:
         pages are never donated to the prefix tree."""
         if not self._running:
             return []
-        done = jax.device_get(self._state["done"])
-        if not done.any():
-            return []
-        out_len = jax.device_get(self._state["out_len"])
-        outs = jax.device_get(self._state["outs"])
-        errf = jax.device_get(self._state["err"])
-        slots = [int(s) for s in np.nonzero(done)[0] if int(s) in self._running]
-        completed = []
-        now = time.time()
-        for slot in sorted(slots, key=lambda s: self._running[s].rid):
-            info = self._running.pop(slot)
-            bad = bool(errf[slot])
-            toks = (np.zeros((0,), np.int32) if bad else
-                    outs[slot, :int(out_len[slot])].astype(np.int32))
-            if bad:
-                self.stats["errors"] += 1
-            else:
-                self.stats["tokens_out"] += int(toks.size)
-                self.stats["completed"] += 1
-            if self._paged:
-                self._retire_slot(slot, info, ok=not bad)
-            self._free.append(slot)
-            self._slot_last_tok.pop(slot, None)
-            completed.append(Completion(
-                info.rid, toks, now - self._submit_time.pop(info.rid),
-                ttft=self._ttft.pop(info.rid, 0.0),
-                ttft_wall=self._ttft_wall.pop(info.rid, 0.0),
-                cached_tokens=self._cached_tokens.pop(info.rid, 0),
-                itl=np.asarray(self._itl.pop(info.rid, [])),
-                status="error" if bad else "completed",
-                error="non-finite logits" if bad else None))
-        idx = jnp.asarray(slots, jnp.int32)
-        self._state = dict(self._state,
-                           done=self._state["done"].at[idx].set(False),
-                           err=self._state["err"].at[idx].set(False))
-        return completed
+        with self._span("serve.harvest"):
+            done = jax.device_get(self._state["done"])
+            if not done.any():
+                return []
+            out_len = jax.device_get(self._state["out_len"])
+            outs = jax.device_get(self._state["outs"])
+            errf = jax.device_get(self._state["err"])
+            slots = [int(s) for s in np.nonzero(done)[0]
+                     if int(s) in self._running]
+            completed = []
+            now = time.perf_counter()
+            for slot in sorted(slots, key=lambda s: self._running[s].rid):
+                info = self._running.pop(slot)
+                bad = bool(errf[slot])
+                toks = (np.zeros((0,), np.int32) if bad else
+                        outs[slot, :int(out_len[slot])].astype(np.int32))
+                if bad:
+                    self.stats["errors"] += 1
+                else:
+                    self.stats["tokens_out"] += int(toks.size)
+                    self.stats["completed"] += 1
+                if self._paged:
+                    self._retire_slot(slot, info, ok=not bad)
+                self._free.append(slot)
+                self._slot_last_tok.pop(slot, None)
+                completed.append(Completion(
+                    info.rid, toks, now - self._submit_time.pop(info.rid),
+                    ttft=self._ttft.pop(info.rid, 0.0),
+                    ttft_wall=self._ttft_wall.pop(info.rid, 0.0),
+                    queue_s=self._queue_s.pop(info.rid, 0.0),
+                    cached_tokens=self._cached_tokens.pop(info.rid, 0),
+                    itl=np.asarray(self._itl.pop(info.rid, [])),
+                    status="error" if bad else "completed",
+                    error="non-finite logits" if bad else None))
+            idx = jnp.asarray(slots, jnp.int32)
+            self._state = dict(self._state,
+                               done=self._state["done"].at[idx].set(False),
+                               err=self._state["err"].at[idx].set(False))
+            return completed
 
     def _plan_tick(self, site: str):
         """Consult the fault plan at a hook point (no-op without one)."""
@@ -922,7 +981,7 @@ class Scheduler:
         Accounts the rid exactly once, like a harvested completion."""
         self.stats["shed" if status == "shed" else "errors"] += 1
         return Completion(rid, np.zeros((0,), np.int32),
-                          time.time() - self._submit_time.pop(rid),
+                          time.perf_counter() - self._submit_time.pop(rid),
                           status=status, error=error)
 
     def _run_burst(self, dr: bool, bounded: int) -> None:
@@ -934,33 +993,35 @@ class Scheduler:
         bit-identical to an unfaulted one. Injected stalls ('slow') and
         backoff sleeps land in decode_s — they are exactly the wall time
         a goodput benchmark must see."""
-        t0 = time.time()
-        for attempt in range(self.burst_retries + 1):
-            try:
-                for f in self._plan_tick("burst"):
-                    if f.kind == "slow":
-                        time.sleep(f.param)       # straggler simulation
-                    elif f.kind == "device_error":
-                        raise TransientDeviceError(
-                            f"injected device error "
-                            f"(burst attempt {attempt})")
-                if self._mesh is None:
-                    self._state, self._cache = self._burst(
-                        self.params, self._state, self._cache, dr, bounded)
-                else:
-                    self._state, self._cache = \
-                        self._sharded_burst(dr, bounded)(
-                            self.params, self._state, self._cache)
-                jax.block_until_ready(self._state["done"])
-                break
-            except TransientDeviceError:
-                self.stats["burst_retries"] += 1
-                if attempt == self.burst_retries:
-                    raise
-                time.sleep(self.backoff_s * (2 ** attempt))
-        self.stats["decode_s"] += time.time() - t0
+        with self._span("serve.burst", "decode_s", slots=len(self._running),
+                        max_steps=bounded) as t0:
+            for attempt in range(self.burst_retries + 1):
+                try:
+                    for f in self._plan_tick("burst"):
+                        if f.kind == "slow":
+                            time.sleep(f.param)   # straggler simulation
+                        elif f.kind == "device_error":
+                            raise TransientDeviceError(
+                                f"injected device error "
+                                f"(burst attempt {attempt})")
+                    if self._mesh is None:
+                        self._state, self._cache = self._burst(
+                            self.params, self._state, self._cache, dr,
+                            bounded)
+                    else:
+                        self._state, self._cache = \
+                            self._sharded_burst(dr, bounded)(
+                                self.params, self._state, self._cache)
+                    jax.block_until_ready(self._state["done"])
+                    break
+                except TransientDeviceError:
+                    self.stats["burst_retries"] += 1
+                    if attempt == self.burst_retries:
+                        raise
+                    time.sleep(self.backoff_s * (2 ** attempt))
         self.stats["bursts"] += 1
-        self._note_burst_tokens(t0)
+        with self._span("serve.itl"):
+            self._note_burst_tokens(t0)
 
     def audit(self) -> list[str]:
         """Cross-layer invariant audit (violation strings; empty ==
@@ -1038,53 +1099,63 @@ class Scheduler:
         return out + self._poll_impl(drain)
 
     def _poll_impl(self, drain: bool) -> list[Completion]:
+        self.stats["polls"] += 1
+        with self._span("serve.poll", "poll_s"):
+            completed = self._admit_queued()
+            if self._admitting:
+                self._advance_admission()
+            completed += self._harvest()
+            if not completed and self._running:
+                bounded = self.interleave_steps if self._admitting else 0
+                dr = drain and not self._queue and not self._admitting
+                self._run_burst(dr, bounded)
+                self._watchdog()
+                completed += self._harvest()
+            return completed
+
+    def _admit_queued(self) -> list[Completion]:
+        """Move queued requests into free slots, each inside its
+        `serve.admit` span; returns those resolved without a slot."""
         completed: list[Completion] = []
         while self._queue and self._free:
             rid, req = self._pop_next()
-            if req.deadline_s is not None and \
-                    time.time() - self._submit_time[rid] > req.deadline_s:
-                # deadline-based load shedding: the TTFT deadline already
-                # passed, so prefill compute would be wasted — shed now
-                completed.append(self._resolve(rid, "shed"))
-                continue
-            slot = self._free.pop(0)
-            poison, injected = 0.0, False
-            for f in self._plan_tick("admit"):
-                if f.kind == "nan":
-                    poison = float("nan")
-                elif f.kind == "poison":
-                    injected = True
-            if injected:
-                self._free.insert(0, slot)
-                completed.append(self._resolve(
-                    rid, "error", "injected poison fault at admission"))
-                continue
-            if self.prefill_chunk:
-                if not self._start_admission(slot, rid, req, poison):
+            with self._span("serve.admit", rid=rid):
+                waited = time.perf_counter() - self._submit_time[rid]
+                if req.deadline_s is not None and waited > req.deadline_s:
+                    # deadline-based load shedding: the TTFT deadline
+                    # already passed, so prefill compute would be wasted
+                    completed.append(self._resolve(rid, "shed"))
+                    continue
+                slot = self._free.pop(0)
+                poison, injected = 0.0, False
+                for f in self._plan_tick("admit"):
+                    if f.kind == "nan":
+                        poison = float("nan")
+                    elif f.kind == "poison":
+                        injected = True
+                if injected:
                     self._free.insert(0, slot)
-                    if not self._running and not self._admitting:
-                        # nothing in flight can ever retire pages for this
-                        # request: it is unsatisfiable — error it alone
-                        # instead of wedging the whole scheduler
-                        completed.append(self._resolve(
-                            rid, "error",
-                            "page pool exhausted with nothing in flight"))
-                        continue
-                    # page pool exhausted even after eviction: requeue and
-                    # wait for in-flight requests to retire their pages
-                    self._queue.appendleft((rid, req))
-                    break
-            else:
-                self._admit(slot, rid, req, poison)
-        if self._admitting:
-            self._advance_admission()
-        completed += self._harvest()
-        if not completed and self._running:
-            bounded = self.interleave_steps if self._admitting else 0
-            dr = drain and not self._queue and not self._admitting
-            self._run_burst(dr, bounded)
-            self._watchdog()
-            completed += self._harvest()
+                    completed.append(self._resolve(
+                        rid, "error", "injected poison fault at admission"))
+                    continue
+                if self.prefill_chunk:
+                    if not self._start_admission(slot, rid, req, poison):
+                        self._free.insert(0, slot)
+                        if not self._running and not self._admitting:
+                            # nothing in flight can ever retire pages for
+                            # this request: it is unsatisfiable — error it
+                            # alone instead of wedging the whole scheduler
+                            completed.append(self._resolve(
+                                rid, "error",
+                                "page pool exhausted with nothing in flight"))
+                            continue
+                        # page pool exhausted even after eviction:
+                        # requeue and wait for in-flight requests to
+                        # retire their pages
+                        self._queue.appendleft((rid, req))
+                        break
+                else:
+                    self._admit(slot, rid, req, poison)
         return completed
 
     def run(self) -> dict[int, Completion]:

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -46,10 +47,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, name, *shapes):
+    """Compile for the described chip; the kernel is a custom call that
+    carries its `name`, which is how a device trace shows it."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}[.0-9]* = .*custom-call", text), name
 
 
 SLOTS = 8                                   # decode rows (serving slots)
@@ -66,17 +70,18 @@ def test_binary_gemm_compiles(one_chip, kernel, d_model):
     if kernel == "vpu":
         fn = functools.partial(binary_gemm_vpu, k_true=k, interpret=False,
                                **tune.TPU_GEMM_TILE)
-        _compile(fn, one_chip, ((m, kw), jnp.uint32), w)
+        _compile(fn, one_chip, "binary_gemm_vpu", ((m, kw), jnp.uint32), w)
     elif kernel == "vpu_packed":
         fn = functools.partial(binary_gemm_vpu_packed, k_true=k,
                                interpret=False, **tune.TPU_GEMM_TILE)
-        _compile(fn, one_chip, ((m, k), jnp.bfloat16), w)
+        _compile(fn, one_chip, "binary_gemm_vpu", ((m, k), jnp.bfloat16), w)
     else:
         lhs = ((m, kw), jnp.uint32) if kernel == "packed_io_bits" \
             else ((m, k), jnp.bfloat16)
         fn = functools.partial(binary_gemm_vpu_packed_io, k_true=k,
                                interpret=False, **tune.TPU_FUSED_TILE)
-        _compile(fn, one_chip, lhs, w, ((n,), jnp.int32), ((n,), jnp.int32))
+        _compile(fn, one_chip, "binary_gemm_fused", lhs, w,
+                 ((n,), jnp.int32), ((n,), jnp.int32))
 
 
 # (kv heads, GQA group, head_dim): musicgen-large MHA, and a GQA model
@@ -117,5 +122,7 @@ def test_packed_attention_compiles(one_chip, kernel, hd, pool):
         fn = decode_attention_packed if s == 1 else prefill_attention_packed
     if s > 1:
         operands.append(lens)                         # q_pos
+    name = "packed_attention_paged" if kernel.endswith("paged") \
+        else "packed_attention"
     _compile(functools.partial(fn, route="pallas", interpret=False, **blocks),
-             one_chip, *operands)
+             one_chip, name, *operands)
