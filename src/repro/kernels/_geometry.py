@@ -120,6 +120,35 @@ def attn_geometry(b: int, s: int, block_b: int, block_q: int, *,
     return AttnGeometry(bb, bq, pb, ps, (b + pb) // bb, (s + ps) // bq)
 
 
+# What the paged attention kernel may hold in VMEM: its panels and its
+# double-buffered blocks, below the TPU's 16 MiB scoped default
+ATTN_VMEM_BUDGET = 12 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def attn_head_block(hkv: int, hdw: int, bb: int, rows: int, hd: int,
+                    n_pos: int, *, aligned: bool = False) -> int:
+    """kv heads per program of the paged attention kernel: the most that
+    divide hkv and keep its VMEM — two (bb, n_pos) panels of hb heads'
+    hdw words, and the double-buffered query, V-sum and weight-sum
+    blocks, each (rows, ·) tile padded to whole (8, 128) tiles when
+    aligned — within ATTN_VMEM_BUDGET (one head, if none fits)."""
+    sub, lane = (SUBLANE, LANE) if aligned else (1, 1)
+
+    def up(x: int, m: int) -> int:
+        return -(-x // m) * m
+
+    def need(hb: int) -> int:
+        panels = 2 * bb * up(hdw, sub) * n_pos
+        blocks = 2 * bb * up(rows, sub) * (
+            up(hdw, lane) + 2 * up(hd, lane) + 2 * up(1, lane))
+        return 4 * hb * (panels + blocks)
+
+    fits = [hb for hb in range(1, hkv + 1)
+            if hkv % hb == 0 and need(hb) <= ATTN_VMEM_BUDGET]
+    return max(fits, default=1)
+
+
 class ShardGeometry(NamedTuple):
     """One tensor-parallel axis split: `dim` rows over `parts` devices."""
     dim: int

@@ -27,9 +27,12 @@ fp32 caches at hd >= 32).
 
 The kernel is the chunked-prefill kernel at S == 1
 (`kernels.prefill_attention.packed_attention` with q_pos = cache_len - 1,
-whose causal mask is then exactly `t < cache_len`): grid (B/block_b, Hkv),
-each program owning `block_b` batch rows of one kv head and their full
-(hdw, T) K/V panels in VMEM. `block_b` is an autotuned knob
+whose causal mask is then exactly `t < cache_len`). Contiguous, its grid
+is (B/block_b, Hkv), each program owning `block_b` batch rows of one kv
+head and their full (hdw, T) K/V panels in VMEM. Paged, a program owns
+`block_b` slots across a block of kv heads (all of them at serving
+widths) and DMAs and sums only the ceil(cache_len / ps) pages each slot
+holds — an idle slot (cache_len 0) none. `block_b` is an autotuned knob
 (repro.kernels.tune) — one row per program maximizes grid parallelism,
 several rows per program amortize per-program overhead. GQA query heads
 for the kv head ride in the same block.
@@ -122,7 +125,7 @@ def decode_attention_packed_paged(q: Array, k_pool: Array, v_pool: Array,
     cache_len: scalar or (B,). Returns (B, 1, Hq, hd) in q.dtype, equal
     to ref.decode_attention_packed_paged_ref up to f32 rounding — and
     bit-exact with the contiguous `decode_attention_packed` whenever NP*ps
-    equals its T (one kernel core; paging is pure addressing).
+    equals its T (exact integer sums; paging is pure addressing).
     """
     p_pool, ps, hkv, hdw = k_pool.shape
     b, np_ = page_table.shape

@@ -24,12 +24,23 @@ cache, plus the chunk's own causal triangle — is exactly this kernel:
     under the weights on the MXU, exactly (`ref.exact_bits_dot`); the
     caller scales by the per-head fp `v_scale` (`ref.fixed_point_out`).
 
-Grid is (B/block_b, Hkv, S/block_q): each program owns `block_b` batch
-rows of one (kv head, query sub-chunk) and streams the full K/V panels
-through VMEM — T*hdw words is ~1/32 of the float K/V a flash-attention
-prefill of the same chunk would read. The panels are laid out (hdw, T),
-positions on the lanes, so the scores, the masks and the softmax are
-lane-dense (B, rows, T) tiles and no block has a lane dim of 1-4 words.
+Contiguous, the grid is (B/block_b, Hkv, S/block_q): each program owns
+`block_b` batch rows of one (kv head, query sub-chunk) and reads their
+whole K/V panels through VMEM — T*hdw words is ~1/32 of the float K/V a
+flash-attention prefill of the same chunk would read. The panels are laid
+out (hdw, T), positions on the lanes, so the scores, the masks and the
+softmax are lane-dense (B, rows, T) tiles and no block has a lane dim of
+1-4 words.
+
+Paged, a program owns `block_b` batch rows of a block of kv heads (every
+head where VMEM allows, `_geometry.attn_head_block`) and one query
+sub-chunk, and walks only the pages its rows' queries can see:
+ceil(min(kv_len, q_pos + q_off + block_q) / ps) of each row's table, one
+DMA per page holding every head's words, none for an idle slot. The
+softmax then takes two passes over those pages (each query row's lowest
+popcount, then the weights and the V sums); the sums are exact integers,
+so the result equals the whole panel's bit for bit.
+
 Both block sizes are autotuned knobs (repro.kernels.tune): block_q trades
 triangle waste against per-program overhead, block_b amortizes that
 overhead across batch rows. GQA query heads ride in the same block.
@@ -58,7 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitpack import WORD, pack_bits
 from repro.kernels import ref
-from repro.kernels._geometry import LANE, attn_geometry
+from repro.kernels._geometry import LANE, attn_geometry, attn_head_block
 
 Array = jax.Array
 
@@ -78,30 +89,46 @@ def packed_scores(qb: Array, kt: Array) -> Array:
     return acc
 
 
-def _attend(qb, kt, vt, lens, qpos, q_off, *, hd: int, group: int,
-            window: int, causal: bool):
-    """Shared attention core: qb (bb, R, hdw) uint32 query rows (row r is
-    query q_off + r // group, GQA head r % group), kt/vt (bb, hdw, T)
-    uint32 panels, lens/qpos (bb, 1, 1) int32. Returns the exact integer
-    limb sums (ref.exact_bits_dot's (bb, R, hd) pair, ref.limb_sums's
-    (bb, R, 1) pair) that `ref.fixed_point_out` turns into the output.
-    The contiguous and paged kernels both end here; paging only changes
-    how the panels were addressed."""
-    rows, t = qb.shape[1], kt.shape[2]
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
-    qp = qpos + q_off + \
-        jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) // group
-    valid = kpos < lens                                        # (bb, 1, T)
+def _valid(kpos, lens, qp, *, window: int, causal: bool) -> Array:
+    """Which key positions `kpos` a query row at global position `qp` may
+    attend in a cache of `lens` valid positions."""
+    valid = kpos < lens
     if causal:
         valid &= kpos <= qp
     if window > 0:
         valid &= kpos > qp - window
-    w = ref.softmax_weights(packed_scores(qb, kt), valid, hd)  # (bb, R, T)
+    return valid
+
+
+def _query_pos(qpos, q_off, rows: int, group: int) -> Array:
+    """(1, rows, 1) global position of each score row: row r is query
+    q_off + r // group (GQA head r % group)."""
+    return qpos + q_off + \
+        jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) // group
+
+
+def _v_sums(w: Array, vt: Array, hd: int):
+    """Exact limb sums of fixed-point weights w (n, R, T) against the V
+    bits of packed panels vt (n, hdw, T), n batch rows or kv heads:
+    ref.exact_bits_dot's (n, R, hd) pair and ref.limb_sums's (n, R, 1)
+    pair, which `ref.fixed_point_out` turns into the output."""
     shifts = jax.lax.broadcasted_iota(jnp.int32, (1, WORD, 1), 1)
     bits = jnp.concatenate([(_i32(vt[:, i:i + 1, :]) >> shifts) & 1
                             for i in range(vt.shape[1])], axis=1)[:, :hd]
     sp = ref.exact_bits_dot(w, bits, (((2,), (2,)), ((0,), (0,))))
     return sp, ref.limb_sums(w)
+
+
+def _attend(qb, kt, vt, lens, qpos, q_off, *, hd: int, group: int,
+            window: int, causal: bool):
+    """Attention core of the contiguous kernel: qb (bb, R, hdw) uint32
+    query rows, kt/vt (bb, hdw, T) uint32 panels, lens/qpos (bb, 1, 1)
+    int32. Returns `_v_sums` over the whole panel."""
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt.shape[2]), 2)
+    valid = _valid(kpos, lens, _query_pos(qpos, q_off, qb.shape[1], group),
+                   window=window, causal=causal)
+    w = ref.softmax_weights(packed_scores(qb, kt), valid, hd)
+    return _v_sums(w, vt, hd)
 
 
 def _kernel(len_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref, l_ref, *,
@@ -125,33 +152,102 @@ def _store(o_ref, l_ref, sums) -> None:
 def _paged_kernel(pt_ref, len_ref, qpos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
                   l_ref, kt_ref, vt_ref, sem, *, hd: int, group: int,
                   window: int, causal: bool):
-    """Paged twin of `_kernel`: kp_hbm/vp_hbm are the whole page pools
-    (Hkv, P, hdw, ps), left in HBM; pt_ref the flat (B * NP,) page tables
-    in SMEM. Each row's pages are DMA'd into (bb, hdw, NP * ps) VMEM
-    panels — only the pages the rows address, so VMEM holds no pool — then
-    the shared core runs on the same panel the contiguous kernel reads.
-    Sentinel entries (== P, unallocated) clip to the last pool page; those
-    rows sit at positions >= kv_len and the length mask drops them."""
-    bb, _, n_pos = kt_ref.shape
-    p_pool, ps = kp_hbm.shape[1], kp_hbm.shape[3]
-    n_pages = n_pos // ps
-    head = pl.program_id(1)
-    copies = []
-    for r in range(bb):
-        base = (pl.program_id(0) * bb + r) * n_pages
-        for i in range(n_pages):
-            page = jnp.minimum(pt_ref[base + i], p_pool - 1)
-            for pool, panel in ((kp_hbm, kt_ref), (vp_hbm, vt_ref)):
-                copies.append(pltpu.make_async_copy(
-                    pool.at[head, page], panel.at[r, :, pl.ds(i * ps, ps)],
-                    sem))
-                copies[-1].start()
-    for copy in copies:
-        copy.wait()
-    _store(o_ref, l_ref, _attend(
-        q_ref[:, 0], kt_ref[...], vt_ref[...], len_ref[...], qpos_ref[...],
-        pl.program_id(2) * (q_ref.shape[2] // group),
-        hd=hd, group=group, window=window, causal=causal))
+    """Paged packed attention over the pages each row holds.
+
+    A program owns `bb` batch rows of `hb` kv heads and one query block:
+    q_ref (bb, hb, R, hdw) uint32, o_ref (2, bb, hb, R, hd) and l_ref
+    (2, bb, hb, R, 1) int32 limb sums. pt_ref (B * NP,) page tables and
+    len_ref / qpos_ref (B,) kv_len and q_pos sit in SMEM; kp_hbm/vp_hbm
+    are the whole pools (P, Hkv / hb, hb, hdw, ps), left in HBM.
+
+    Row b's queries see positions < min(kv_len, q_pos + q_off + bq), so
+    only its first `hi` table pages are live. Each live page is one DMA
+    of every head's words into the (bb, NP, hb, hdw, ps) VMEM panels, and
+    the scores, softmax and V sums walk those pages alone, the heads as
+    the batch dim of `_attend`'s ops: pass 1 finds each query row's
+    lowest popcount, pass 2 sums the fixed-point weights it fixes
+    (`ref.softmax_weights`). Every sum is an exact integer sum, so the
+    result equals `_attend`'s over the whole panel bit for bit. A row with
+    no live page (kv_len <= 0: an idle slot, or a pad row) issues no DMA
+    and gives zero sums."""
+    bb, hb, rows, _ = q_ref.shape
+    n_pages, ps = kt_ref.shape[1], kt_ref.shape[4]
+    p_pool = kp_hbm.shape[0]
+    heads = pl.program_id(1)
+    q_off = pl.program_id(2) * (rows // group)
+    b0 = pl.program_id(0) * bb
+
+    def live(b):
+        n = len_ref[b]
+        if causal:
+            n = jnp.minimum(n, qpos_ref[b] + q_off + rows // group)
+        return jnp.clip((n + ps - 1) // ps, 0, n_pages)
+
+    def copy(r, i, page, kv):
+        pool, panel = ((kp_hbm, kt_ref), (vp_hbm, vt_ref))[kv]
+        return pltpu.make_async_copy(pool.at[page, heads], panel.at[r, i],
+                                     sem.at[kv, r])
+
+    @pl.loop(0, bb)
+    def _(r):
+        @pl.loop(0, live(b0 + r))
+        def _(i):
+            # live pages are allocated; the clip keeps a stray sentinel
+            # (== P) from addressing past the pool
+            page = jnp.minimum(pt_ref[(b0 + r) * n_pages + i], p_pool - 1)
+            for kv in (0, 1):
+                copy(r, i, page, kv).start()
+
+    @pl.loop(0, bb)
+    def _(r):
+        b = b0 + r
+        hi = live(b)
+        qp = _query_pos(qpos_ref[b], q_off, rows, group)
+
+        def wait(kv):
+            @pl.loop(0, hi)
+            def _(i):
+                copy(r, 0, 0, kv).wait()
+
+        def scores(i):
+            """Popcounts (hb, R, ps) and mask (1, R, ps) of live page i."""
+            kpos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2)
+            valid = _valid(kpos, len_ref[b], qp, window=window,
+                           causal=causal)
+            return packed_scores(q_ref[r], kt_ref[r, i]), valid
+
+        def low(i, lowest):
+            pc, valid = scores(i)
+            return jnp.minimum(lowest, jnp.min(
+                jnp.where(valid, pc, hd + 1), axis=-1, keepdims=True))
+
+        wait(0)
+        lowest = jax.lax.fori_loop(
+            0, hi, low, jnp.full((hb, rows, 1), hd + 1, jnp.int32))
+        o_ref[:, r] = jnp.zeros(o_ref.shape[:1] + o_ref.shape[2:], jnp.int32)
+        l_ref[:, r] = jnp.zeros(l_ref.shape[:1] + l_ref.shape[2:], jnp.int32)
+        wait(1)
+
+        @pl.loop(0, hi)
+        def _(i):
+            pc, valid = scores(i)
+            w = ref.softmax_weights(pc, valid, hd, lowest)
+            for ref_, pair in zip((o_ref, l_ref),
+                                  _v_sums(w, vt_ref[r, i], hd)):
+                for limb, x in enumerate(pair):
+                    ref_[limb, r] += x
+
+
+def _q_spec(bb: int, hb: int, rows: int, hdw: int):
+    return pl.BlockSpec((bb, hb, rows, hdw),
+                        lambda i, j, kk, *_: (i, j, kk, 0))
+
+
+def _out_specs(bb: int, hb: int, rows: int, hd: int):
+    """The high and low limbs of the V sums and of the weight sums."""
+    return [pl.BlockSpec((2, bb, hb, rows, n),
+                         lambda i, j, kk, *_: (0, i, j, kk, 0))
+            for n in (hd, 1)]
 
 
 def _rows(x, b: int) -> Array:
@@ -190,34 +286,28 @@ def packed_attention(q: Array, k: Array, v: Array, v_scale: Array,
     lens, qpos = _rows(kv_len, b), _rows(q_pos, b)
     if geo.pb:
         qb = jnp.pad(qb, ((0, geo.pb),) + ((0, 0),) * 3)
-        # pad rows get kv_len 1 / q_pos 0 — finite math, sliced off below
-        lens = jnp.pad(lens, ((0, geo.pb), (0, 0), (0, 0)),
-                       constant_values=1)
+        # pad rows get kv_len 0 / q_pos 0: no valid position, zero sums,
+        # sliced off below
+        lens = jnp.pad(lens, ((0, geo.pb), (0, 0), (0, 0)))
         qpos = jnp.pad(qpos, ((0, geo.pb), (0, 0), (0, 0)))
-    small = pl.BlockSpec((bb, 1, 1), lambda i, j, kk, *_: (i, 0, 0))
-    q_spec = pl.BlockSpec((bb, 1, rows, hdw),
-                          lambda i, j, kk, *_: (i, j, kk, 0))
-    out_spec = [pl.BlockSpec((2, bb, 1, rows, n),
-                             lambda i, j, kk, *_: (0, i, j, kk, 0))
-                for n in (hd, 1)]
     out_shape = [jax.ShapeDtypeStruct((2, b + geo.pb, hkv, s_pad * g, n),
                                       jnp.int32) for n in (hd, 1)]
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel"))
     body = dict(hd=hd, group=g, window=window, causal=causal)
-    grid = (geo.gb, hkv, geo.gs)
     if page_table is None:
         kt = k.transpose(0, 2, 3, 1)                           # (B,Hkv,hdw,T)
         vt = v.transpose(0, 2, 3, 1)
         if geo.pb:
             row_pad = ((0, geo.pb),) + ((0, 0),) * 3
             kt, vt = jnp.pad(kt, row_pad), jnp.pad(vt, row_pad)
+        small = pl.BlockSpec((bb, 1, 1), lambda i, j, kk: (i, 0, 0))
         panel = pl.BlockSpec((bb, 1, hdw, kt.shape[3]),
                              lambda i, j, kk: (i, j, 0, 0))
         sp, l = pl.pallas_call(
-            functools.partial(_kernel, **body), grid=grid,
-            in_specs=[small, small, q_spec, panel, panel],
-            out_specs=out_spec, out_shape=out_shape,
+            functools.partial(_kernel, **body), grid=(geo.gb, hkv, geo.gs),
+            in_specs=[small, small, _q_spec(bb, 1, rows, hdw), panel, panel],
+            out_specs=_out_specs(bb, 1, rows, hd), out_shape=out_shape,
             compiler_params=params, interpret=interpret,
             name="packed_attention",
         )(lens, qpos, qb, kt, vt)
@@ -228,25 +318,30 @@ def packed_attention(q: Array, k: Array, v: Array, v_scale: Array,
             raise ValueError(f"page_size {ps}: a page is a lane slice of "
                              f"the VMEM panel, so on the TPU it must be a "
                              f"multiple of {LANE}")
-        kp = k.transpose(2, 0, 3, 1)                           # (Hkv,P,hdw,ps)
-        vp = v.transpose(2, 0, 3, 1)
+        hb = attn_head_block(hkv, hdw, bb, rows, hd, n_pages * ps,
+                             aligned=not interpret)
+        # (P, ps, Hkv, hdw) -> (P, Hkv/hb, hb, hdw, ps): one page of a head
+        # block is one contiguous DMA
+        kp, vp = (x.transpose(0, 2, 3, 1).reshape(p_pool, hkv // hb, hb,
+                                                  hdw, ps)
+                  for x in (k, v))
         pt = jnp.asarray(page_table, jnp.int32)
         if geo.pb:
-            # pad rows: all-sentinel tables, clipped behind the length mask
+            # pad rows: all-sentinel tables behind kv_len 0, never walked
             pt = jnp.pad(pt, ((0, geo.pb), (0, 0)), constant_values=p_pool)
         pool = pl.BlockSpec(memory_space=pl.ANY)
         sp, l = pl.pallas_call(
             functools.partial(_paged_kernel, **body),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=grid,
-                in_specs=[small, small, q_spec, pool, pool],
-                out_specs=out_spec,
-                scratch_shapes=[pltpu.VMEM((bb, hdw, n_pages * ps),
+                num_scalar_prefetch=3, grid=(geo.gb, hkv // hb, geo.gs),
+                in_specs=[_q_spec(bb, hb, rows, hdw), pool, pool],
+                out_specs=_out_specs(bb, hb, rows, hd),
+                scratch_shapes=[pltpu.VMEM((bb, n_pages, hb, hdw, ps),
                                            jnp.uint32)] * 2
-                + [pltpu.SemaphoreType.DMA(())]),
+                + [pltpu.SemaphoreType.DMA((2, bb))]),
             out_shape=out_shape, compiler_params=params,
             interpret=interpret, name="packed_attention_paged",
-        )(pt.reshape(-1), lens, qpos, qb, kp, vp)
+        )(pt.reshape(-1), lens.reshape(-1), qpos.reshape(-1), qb, kp, vp)
     out = ref.fixed_point_out(
         sp[:, :b].reshape(2, b, hkv, s_pad, g, hd),
         l[:, :b].reshape(2, b, hkv, s_pad, g, 1),
@@ -321,7 +416,7 @@ def prefill_attention_packed_paged(q: Array, k_pool: Array, v_pool: Array,
     (B, S, Hq, hd) in q.dtype, equal to
     ref.prefill_attention_packed_paged_ref up to f32 rounding — and
     bit-exact with the contiguous `prefill_attention_packed` whenever
-    NP*ps equals its T (shared `_attend` core; paging is pure addressing).
+    NP*ps equals its T (exact integer sums; paging is pure addressing).
     """
     p_pool, ps, hkv, hdw = k_pool.shape
     b, np_ = page_table.shape

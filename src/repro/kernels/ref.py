@@ -59,15 +59,20 @@ def _softmax_rungs(hd: int) -> list[float]:
             for k in range(hd.bit_length())]
 
 
-def softmax_weights(pc: Array, valid: Array, hd: int) -> Array:
+def softmax_weights(pc: Array, valid: Array, hd: int,
+                    lowest: Array | None = None) -> Array:
     """Fixed-point softmax numerators from xor popcounts.
 
     pc: (..., T) int32 popcount(xor(q_bits, k_bits_t)) — the sign dot is
     hd - 2*pc, so the lowest popcount is the top score; valid: bool,
-    broadcastable to pc. Returns (..., T) int32 in [0, 2^30]:
-    2^30 * exp(score_t - max score), masked positions 0."""
+    broadcastable to pc; lowest: (..., 1) the row's lowest valid popcount
+    (hd + 1 if none), when pc holds only part of the row. Returns
+    (..., T) int32 in [0, 2^30]: 2^30 * exp(score_t - max score), masked
+    positions 0."""
     pc = jnp.where(valid, pc, jnp.int32(hd + 1))
-    j = pc - jnp.min(pc, axis=-1, keepdims=True)
+    if lowest is None:
+        lowest = jnp.min(pc, axis=-1, keepdims=True)
+    j = pc - lowest
     e = jnp.ones(j.shape, jnp.float32)
     for k, rung in enumerate(_softmax_rungs(hd)):
         e = jnp.where(((j >> k) & 1) != 0, e * jnp.float32(rung), e)

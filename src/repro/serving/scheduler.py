@@ -300,7 +300,9 @@ class Scheduler:
         # `chunk_kv` the key positions each call reads; `decode_tokens`
         # are tokens sampled by decode steps, `decode_keys` the keys they
         # attend (token j of an L-token prompt attends L + j positions);
-        # `first_tokens` count admissions finished
+        # `first_tokens` count admissions finished; paged, `decode_pages`
+        # are the table pages those keys lie in, ceil((L + j) / page_size)
+        # for token j: the pages the paged kernel walks per layer and head
         self.stats = {"prefill_tokens": 0, "prefill_s": 0.0, "bursts": 0,
                       "decode_s": 0.0, "tokens_out": 0, "completed": 0,
                       "max_admit_stall_tokens": 0,
@@ -310,6 +312,8 @@ class Scheduler:
                       "polls": 0, "poll_s": 0.0, "chunks": 0,
                       "chunk_keys": 0, "chunk_kv": 0, "decode_tokens": 0,
                       "decode_keys": 0, "first_tokens": 0}
+        if self._paged:
+            self.stats["decode_pages"] = 0
 
         self._cache = model.init_cache(n_slots, max_len, **cache_kw)
         self._state = {
@@ -892,7 +896,8 @@ class Scheduler:
         BEFORE the burst (e.g. behind another request's admission) lands
         on its first token's interval — so a head-of-line-blocking prefill
         shows up as one large interval instead of being amortized away.
-        The same counts feed `decode_tokens` and `decode_keys`."""
+        The same counts feed `decode_tokens`, `decode_keys` and
+        `decode_pages`."""
         now = time.perf_counter()
         dur = now - t_start
         out_len = np.asarray(jax.device_get(self._state["out_len"]))
@@ -908,6 +913,10 @@ class Scheduler:
                 self.stats["decode_tokens"] += n
                 self.stats["decode_keys"] += \
                     n * info.prompt_len + (first + last - 1) * n // 2
+                if self._paged:
+                    self.stats["decode_pages"] += sum(
+                        -(-(info.prompt_len + j) // self.page_size)
+                        for j in range(first, last))
             self._prev_out_len[slot] = last
 
     def _harvest(self) -> list[Completion]:

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core.bitpack import pack_bits
-from repro.kernels import ref
+from repro.kernels import _geometry, ref
 from repro.kernels.decode_attention import (
     decode_attention_packed, decode_attention_packed_paged, v_cache_scale,
 )
@@ -206,3 +206,100 @@ def test_tpu_path_rejects_unaligned_page_size():
         decode_attention_packed_paged(q, pool, pool, jnp.ones((b, hkv)), pt,
                                       jnp.full((b,), t), route="pallas",
                                       interpret=False)
+
+
+# ---------------------------------------------------------------------------
+# The walk: the kernel DMAs and sums only the pages a row's queries can see
+# ---------------------------------------------------------------------------
+PS, NP = 8, 4
+# per-row kv_len: empty, one position, page edges, the whole table, idle
+RAGGED = [0, 1, PS - 1, PS, PS + 1, NP * PS, -1]
+
+
+def _walk_case(seed, b, s, hq, hkv, hd):
+    """q, K/V pools of random sign bits, per-head v_scale, and tables whose
+    every entry is a distinct real page: the pages past a row's live ones
+    hold random bits too, so a page walked by mistake would show."""
+    rng = np.random.default_rng(seed)
+    p_pool = b * NP + 2
+    pools = [pack_bits(jnp.asarray(rng.standard_normal(
+        (p_pool, PS, hkv, hd)), jnp.float32)) for _ in range(2)]
+    pt = jnp.asarray(rng.permutation(p_pool)[:b * NP].reshape(b, NP),
+                     jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, s, hq, hd)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.5, 1.5, (b, hkv)), jnp.float32)
+    return q, pools, pt, vs
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("block_b", [1, 4])
+@pytest.mark.parametrize("hq,hkv,hd,window", [
+    (8, 2, 40, 0),        # GQA, two-word hd with pad bits
+    (4, 4, 32, PS + 3),   # MHA, sliding window across page edges
+])
+def test_paged_decode_walks_only_live_pages(hq, hkv, hd, window, block_b):
+    """Rows of one batch at 0, 1, ..., NP live pages (and an idle row):
+    the paged kernel equals the oracle and the contiguous kernel on the
+    gathered cache (NP * ps = T) bit for bit."""
+    q, (k_pool, v_pool), pt, vs = _walk_case(hd + window, len(RAGGED), 1,
+                                             hq, hkv, hd)
+    lens = jnp.asarray(RAGGED, jnp.int32)
+    want = np.asarray(ref.decode_attention_packed_paged_ref(
+        q, k_pool, v_pool, vs, pt, lens, window=window))
+    contiguous = np.asarray(decode_attention_packed(
+        q, ref.gather_pages(k_pool, pt), ref.gather_pages(v_pool, pt), vs,
+        lens, window=window, route="pallas", block_b=block_b,
+        interpret=True))
+    got = np.asarray(decode_attention_packed_paged(
+        q, k_pool, v_pool, vs, pt, lens, window=window, route="pallas",
+        block_b=block_b, interpret=True))
+    np.testing.assert_array_equal(want, contiguous)
+    np.testing.assert_array_equal(want, got)
+    assert not got[np.asarray(lens) <= 0].any()       # idle rows: zeros
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("block_q,block_b", [(4, 1), (2, 2)])
+@pytest.mark.parametrize("window", [0, PS + 3])
+def test_paged_chunk_mid_table_walks_only_live_pages(window, block_q,
+                                                     block_b):
+    """An 8-row chunk whose q_pos sits mid-page, across a page edge, at
+    the table's start with a partial chunk, and on a row with nothing
+    written, in block_q sub-chunks that each see a different page count."""
+    s = 8
+    q, (k_pool, v_pool), pt, vs = _walk_case(block_q + window, 4, s, 4, 2,
+                                             33)
+    q_pos = jnp.asarray([PS + 3, 2 * PS - 2, 0, PS], jnp.int32)
+    kv_len = jnp.asarray([PS + 3 + s, 2 * PS - 2 + s, 5, 0], jnp.int32)
+    want = np.asarray(ref.prefill_attention_packed_paged_ref(
+        q, k_pool, v_pool, vs, pt, kv_len, q_pos, window=window))
+    blocks = dict(route="pallas", block_q=block_q, block_b=block_b,
+                  interpret=True)
+    contiguous = np.asarray(prefill_attention_packed(
+        q, ref.gather_pages(k_pool, pt), ref.gather_pages(v_pool, pt), vs,
+        kv_len, q_pos, window=window, **blocks))
+    got = np.asarray(prefill_attention_packed_paged(
+        q, k_pool, v_pool, vs, pt, kv_len, q_pos, window=window, **blocks))
+    np.testing.assert_array_equal(want, contiguous)
+    np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("budget,heads", [(1, 1), (2600, 2)])
+def test_paged_head_blocks_agree(monkeypatch, budget, heads):
+    """Under a smaller VMEM budget a program owns fewer kv heads: the
+    output stays bit for bit the oracle's."""
+    monkeypatch.setattr(_geometry, "ATTN_VMEM_BUDGET", budget)
+    _geometry.attn_head_block.cache_clear()
+    try:
+        assert _geometry.attn_head_block(4, 1, 1, 1, 32, NP * PS) == heads
+        q, (k_pool, v_pool), pt, vs = _walk_case(heads, len(RAGGED), 1, 4,
+                                                 4, 32)
+        lens = jnp.asarray(RAGGED, jnp.int32)
+        want = np.asarray(ref.decode_attention_packed_paged_ref(
+            q, k_pool, v_pool, vs, pt, lens))
+        got = np.asarray(decode_attention_packed_paged(
+            q, k_pool, v_pool, vs, pt, lens, route="pallas", interpret=True))
+        np.testing.assert_array_equal(want, got)
+    finally:
+        _geometry.attn_head_block.cache_clear()

@@ -23,10 +23,11 @@ def model():
     return cfg, m, m.init(jax.random.PRNGKey(0))
 
 
-def _serve(model, prefill_chunk, mix=MIX):
+def _serve(model, prefill_chunk, mix=MIX, page_size=None):
     cfg, m, params = model
     sched = Scheduler(cfg, m, params, n_slots=2, max_len=32,
-                      prefill_chunk=prefill_chunk, interleave_steps=2)
+                      prefill_chunk=prefill_chunk, interleave_steps=2,
+                      page_size=page_size)
     rng = np.random.default_rng(0)
     rids = [sched.submit(Request(rng.integers(0, cfg.vocab, plen,
                                               dtype=np.int32), n))
@@ -72,6 +73,20 @@ def test_counters_match_a_hand_count(model, prefill_chunk):
                                     for j in range(1, n))
     assert [c.tokens.size for c in comps] == [n for _, n in MIX]
     assert st["poll_s"] >= st["prefill_s"] + st["decode_s"] > 0
+
+
+@pytest.mark.parametrize("page_size", [None, 4, 8])
+def test_decode_pages_count_the_pages_the_decode_rows_hold(model, page_size):
+    """Paged, token j of an L-token prompt comes from a decode step whose
+    row holds ceil((L + j) / page_size) table pages: the pages the paged
+    attention kernel walks for it. Unpaged there are no pages to count."""
+    sched, comps, _ = _serve(model, CHUNK, page_size=page_size)
+    assert [c.tokens.size for c in comps] == [n for _, n in MIX]
+    if page_size is None:
+        assert "decode_pages" not in sched.stats
+        return
+    assert sched.stats["decode_pages"] == sum(
+        -(-(plen + j) // page_size) for plen, n in MIX for j in range(1, n))
 
 
 def test_queue_wait_is_the_wait_before_the_first_prefill_call(model):

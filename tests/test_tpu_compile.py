@@ -126,3 +126,29 @@ def test_packed_attention_compiles(one_chip, kernel, hd, pool):
         else "packed_attention"
     _compile(functools.partial(fn, route="pallas", interpret=False, **blocks),
              one_chip, name, *operands)
+
+
+# musicgen-large's serving cell: 64 slots, 32 kv heads of 64, a pool of 288
+# pages of 128, tables of 16 pages; decode and a 128-row chunk
+CELL_A = dict(slots=64, hkv=32, hd=64, pool=288)
+
+
+@pytest.mark.parametrize("kernel", ["decode_paged", "prefill_paged"])
+def test_paged_attention_compiles_at_the_cells_shape(one_chip, kernel):
+    """At the largest block_b the tuner tries: the head block keeps the
+    kernel's VMEM panels and blocks within the scoped limit."""
+    hkv, hd = CELL_A["hkv"], CELL_A["hd"]
+    hdw = packed_width(hd)
+    b, s = (CELL_A["slots"], 1) if kernel == "decode_paged" else (1, CHUNK)
+    blocks = dict(tune.TPU_DECODE_BLOCKS if s == 1 else
+                  tune.TPU_PREFILL_BLOCKS, block_b=max(tune.DECODE_BLOCK_B))
+    pages = ((CELL_A["pool"], PAGE, hkv, hdw), jnp.uint32)
+    lens = ((b,), jnp.int32)
+    operands = [((b, s, hkv, hd), jnp.bfloat16), pages, pages,
+                ((b, hkv), jnp.float32), ((b, T // PAGE), jnp.int32), lens]
+    fn = decode_attention_packed_paged
+    if s > 1:
+        operands.append(lens)                         # q_pos
+        fn = prefill_attention_packed_paged
+    _compile(functools.partial(fn, route="pallas", interpret=False, **blocks),
+             one_chip, "packed_attention_paged", *operands)
