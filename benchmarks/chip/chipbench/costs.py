@@ -46,27 +46,32 @@ def least_time(work: Work, peak: dict) -> tuple[float, str]:
     return (t_ops, "compute") if t_ops >= t_mem else (t_mem, "memory")
 
 
+def attn_gemm_shapes(m: dict) -> list[tuple[int, int]]:
+    """(K, N) of each binary attention GEMM of one block, in call order."""
+    d, h, kv, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    return [(d, h * hd), (d, kv * hd), (d, kv * hd), (h * hd, d)]
+
+
+def ffn_gemm_shapes(m: dict) -> list[tuple[int, int]]:
+    """(K, N) of each binary GEMM of one FFN (or one expert), in call
+    order."""
+    d, f = m["d_model"], m["d_ff"]
+    gate = [(d, f)] if m["mlp"] in ("swiglu", "geglu") else []
+    return gate + [(d, f), (f, d)]
+
+
 def gemm_shapes(m: dict) -> list[tuple[int, int]]:
-    """(K, N) of each binary GEMM of one block, in call order."""
-    d, h, kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                       m["head_dim"], m["d_ff"])
-    out = [(d, h * hd), (d, kv * hd), (d, kv * hd), (h * hd, d)]
-    if m["mlp"] in ("swiglu", "geglu"):
-        out.append((d, f))
-    return out + [(d, f), (f, d)]
+    """(K, N) of each binary GEMM of one dense block, in call order."""
+    return attn_gemm_shapes(m) + ffn_gemm_shapes(m)
 
 
-def gemm(rows: float, k: int, n: int) -> Work:
-    """One +-1 GEMM: (rows, K) signs times (K, N) signs."""
-    return Work(2.0 * rows * k * n, n * k / 8 + rows * k / 8
-                + rows * n * ACT_BYTES)
-
-
-def block_gemms(m: dict, rows: float, calls: float = 1.0) -> Work:
-    """Every layer's GEMMs for `calls` calls that together carry `rows`
-    rows: the weights are read once per call."""
+def block_gemms(m: dict, rows: float, calls: float = 1.0,
+                shapes: list[tuple[int, int]] | None = None) -> Work:
+    """Every layer's GEMMs (`shapes`, by default all of a dense block's)
+    for `calls` calls that together carry `rows` rows: the weights are
+    read once per call."""
     w = Work()
-    for k, n in gemm_shapes(m):
+    for k, n in gemm_shapes(m) if shapes is None else shapes:
         w = w + Work(2.0 * rows * k * n,
                      calls * n * k / 8 + rows * (k / 8 + n * ACT_BYTES))
     return w.scale(m["n_layers"])
@@ -90,13 +95,32 @@ def head(m: dict, rows: float, calls: float = 1.0) -> Work:
                 + rows * (d + v) * ACT_BYTES)
 
 
-def decode_keys(prompt_len: int, first: int, last: int) -> int:
+def _sum_capped(a: int, b: int, window: int) -> int:
+    """Sum of min(v, window) over v = a..b; window 0 caps nothing."""
+    over = 0                                   # values above the cap
+    if window:
+        over = max(0, b - max(a, window + 1) + 1)
+        b = min(b, window)
+    below = (a + b) * (b - a + 1) // 2 if b >= a else 0
+    return below + window * over
+
+
+def decode_keys(prompt_len: int, first: int, last: int,
+                window: int = 0) -> int:
     """Keys the decode steps producing tokens first..last-1 attend: token
-    j of a request with an L-token prompt attends L + j positions."""
-    n = last - first
-    return n * prompt_len + (first + last - 1) * n // 2 if n > 0 else 0
+    j of a request with an L-token prompt attends L + j positions, at
+    most `window` of them where the layer has a window."""
+    return _sum_capped(prompt_len + first, prompt_len + last - 1, window)
 
 
-def chunk_keys(lo: int, hi: int) -> int:
-    """Keys a prefill chunk of positions lo..hi-1 attends (causal)."""
-    return sum(range(lo + 1, hi + 1))
+def chunk_keys(lo: int, hi: int, window: int = 0) -> int:
+    """Keys a prefill chunk of positions lo..hi-1 attends (causal): the
+    query at position p attends p + 1 keys, at most `window`."""
+    return _sum_capped(lo + 1, hi, window)
+
+
+def chunk_reads(lo: int, hi: int, window: int = 0) -> int:
+    """Key positions whose K and V a prefill chunk of positions lo..hi-1
+    reads: every one below hi, or with a window those from the first
+    query's window on."""
+    return hi - (max(0, lo - window + 1) if window else 0)

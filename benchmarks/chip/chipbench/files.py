@@ -2,7 +2,8 @@
 
 A cell names a configuration and a traffic mix; each lives in a file of
 its own under this benchmark's directory, and each per-layer metric is a
-reader of its own under `metrics/`. Adding a cell, a configuration, a mix
+reader of its own under `metrics/`. A configuration names its plain
+reference (`reference/`) and its block module (`blocks/`). Adding a cell, a configuration, a mix
 or a metric adds files and entries; nothing here names one of them.
 """
 from __future__ import annotations
@@ -60,6 +61,13 @@ def load_module(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_block(cfg: dict):
+    """The configuration's block module, `blocks/<its "block">.py`
+    (`dense` where it names none): the program's tree, the window's work
+    and the CPU tests' cut of that kind of layer stack."""
+    return load_module("blocks", cfg.get("block", "dense"))
 
 
 def metrics_for(spec: dict, cell: str, section: str) -> list[dict]:

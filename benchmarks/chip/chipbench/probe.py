@@ -5,9 +5,11 @@ of tokens generated, of chunks run or of first tokens given. To count the
 work inside a window exactly, the harness reads the scheduler's own
 bookkeeping at the window's two edges (between polls, when the device is
 idle): which request holds which slot and how many tokens it has, which
-admissions are mid-way and at which chunk. `window_work` turns two such snapshots and
-the requests delivered between them into tokens decoded, keys attended,
-chunks prefilled and rows occupied.
+admissions are mid-way and at which chunk. `window_work` turns two such
+snapshots and the requests delivered between them into tokens decoded,
+keys attended, chunks prefilled and rows occupied; the configuration's
+block module (`blocks/<name>.py`, `work_of`) turns those into operations
+and bytes.
 """
 from __future__ import annotations
 
@@ -40,6 +42,18 @@ class WindowWork:
     chunk_keys: int = 0          # sum over chunk queries of keys attended
     chunk_kv: int = 0            # key positions each chunk reads, summed
     firsts: int = 0              # first tokens (one per finished admission)
+    # what the totals sum, for layers that attend a window: each
+    # request's decode (plen, first, last) and each chunk's (lo, hi)
+    decodes: list = dataclasses.field(default_factory=list)
+    spans: list = dataclasses.field(default_factory=list)
+
+    def keys(self, window: int = 0) -> tuple[int, int, int]:
+        """(decode keys, chunk keys, chunk key reads) of a layer that
+        attends at most `window` positions (0: every one); at 0 they are
+        `decode_keys`, `chunk_keys` and `chunk_kv`."""
+        return (sum(costs.decode_keys(*d, window) for d in self.decodes),
+                sum(costs.chunk_keys(*c, window) for c in self.spans),
+                sum(costs.chunk_reads(*c, window) for c in self.spans))
 
 
 def window_work(a: dict, b: dict, reqs: dict, delivered: set,
@@ -55,6 +69,7 @@ def window_work(a: dict, b: dict, reqs: dict, delivered: set,
         last = b["running"].get(rid, n)
         w.decoded += max(0, last - first)
         w.decode_keys += costs.decode_keys(plen, first, last)
+        w.decodes.append((plen, first, last))
         if rid not in started:
             w.firsts += 1
     for rid in set(b["running"]) | delivered | set(b["admitting"]):
@@ -73,15 +88,6 @@ def window_work(a: dict, b: dict, reqs: dict, delivered: set,
             w.chunk_rows += hi - lo
             w.chunk_keys += costs.chunk_keys(lo, hi)
             w.chunk_kv += hi
+            w.spans.append((lo, hi))
     return w
 
-
-def work_of(m: dict, w: WindowWork) -> dict[str, costs.Work]:
-    """The window's GEMM, attention and head work by the cost functions."""
-    gemm = costs.block_gemms(m, w.decoded, w.steps) + \
-        costs.block_gemms(m, w.chunk_rows, w.chunks)
-    attn = costs.attention(m, w.decode_keys, w.decoded, w.decode_keys) + \
-        costs.attention(m, w.chunk_keys, w.chunk_rows, w.chunk_kv)
-    head = costs.head(m, w.decoded, w.steps) + costs.head(m, w.firsts,
-                                                          w.firsts)
-    return {"gemm": gemm, "attn": attn, "head": head}

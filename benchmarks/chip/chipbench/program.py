@@ -1,19 +1,17 @@
 """The system under test: a frozen `ServingEngine` over the configuration.
 
 This is the only module of the benchmark that imports the program, apart
-from `weights.build_program_params` (which hands it the packed tree).
+from the block modules (`blocks/<name>.py`, whose `params_fn` hands it the
+packed tree).
 """
 from __future__ import annotations
 
+import dataclasses
 import sys
 
 from chipbench.files import ROOT
 
 SRC = ROOT / "src"
-# keys of a configuration's "model" section that ModelConfig takes
-MODEL_KEYS = ("family", "n_layers", "d_model", "n_heads", "n_kv_heads",
-              "head_dim", "d_ff", "vocab", "mlp", "norm", "pos", "rope_theta",
-              "quant", "dtype", "kv_bits")
 
 
 def import_program() -> None:
@@ -25,10 +23,14 @@ def import_program() -> None:
 
 
 def model_config(cfg: dict):
+    """The program's ModelConfig: every key of the configuration's "model"
+    that is one of its fields (keys only the reference reads, such as
+    `layernorm_eps`, are left out)."""
     from repro.configs.base import ModelConfig
-    m = cfg["model"]
-    return ModelConfig(name=cfg["name"], source=cfg["source"],
-                       **{k: m[k] for k in MODEL_KEYS if k in m})
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    kw = {k: tuple(v) if isinstance(v, list) else v
+          for k, v in cfg["model"].items() if k in fields}
+    return ModelConfig(name=cfg["name"], source=cfg["source"], **kw)
 
 
 def build_engine(cfg: dict, params, seed: int):

@@ -13,7 +13,9 @@
              each latency counts the whole wait under the same load
     check    memory peak read, the engine freed, the plain reference run
              over a seeded sample of the requests delivered since the
-             window opened, and every compared number held to its limit
+             window opened (as many as the configuration's check names
+             under `checked_requests`), and every compared number held to
+             its limit
 """
 from __future__ import annotations
 
@@ -30,7 +32,6 @@ from chipbench import costs, files, probe, program, stats, trace, traffic
 from chipbench import weights
 
 COMPILE_PASS_NEW = 12          # tokens each compile-pass request asks for
-SAMPLE = 3                     # requests compared with the reference
 TRACE_AT, TRACE_S = 0.4, 4.0   # traced share of the window: start, seconds
 DRAIN_S = 120.0                # longest the client serves past the window
 PALLAS_ROUTES = {"vpu", "pallas"}   # routes that run a Pallas kernel
@@ -172,15 +173,15 @@ def compile_pass(client: Client, mix: dict, cfg: dict, seed: int) -> None:
         client.poll()
 
 
-def _sample(records: list[dict], seed: int) -> list[dict]:
-    """The longest delivered request and SAMPLE - 1 others drawn from the
+def _sample(records: list[dict], seed: int, k: int) -> list[dict]:
+    """The longest delivered request and k - 1 others drawn from the
     seed."""
     if not records:
         return []
     order = sorted(records, key=lambda r: (-r["n"], r["rid"]))
     rest = order[1:]
     rng = np.random.default_rng([seed, 4])
-    pick = rng.choice(len(rest), size=min(SAMPLE - 1, len(rest)),
+    pick = rng.choice(len(rest), size=min(k - 1, len(rest)),
                       replace=False) if rest else []
     return [order[0]] + [rest[i] for i in sorted(pick)]
 
@@ -223,6 +224,19 @@ def gap_numbers(gaps: list) -> dict[str, float]:
     return {"logit_gap_mean": float(np.mean(np.concatenate(gaps)))}
 
 
+def log_gaps(log, sample: list[dict], gaps: list, who: str) -> None:
+    """Each compared request's mean gap and the first served position
+    whose gap is above 0, so that a reading can be traced to its
+    requests."""
+    parts = []
+    for r, g in zip(sample, gaps):
+        above = np.flatnonzero(g > 0)
+        first = int(above[0]) if above.size else -1
+        parts.append(f"{r['rid']}:{r['n']}:{float(np.mean(g)):.4g}:{first}")
+    log(f"gaps of the {who} by request (rid:served:mean:first above 0): "
+        + " ".join(parts))
+
+
 def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
              seconds: float, do_trace: bool, t_process: float, log,
              control: bool = False) -> dict:
@@ -235,8 +249,9 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     traffic.check(mix, s["max_len"])
     peak = costs.peaks(dev.device_kind) if dev.platform == "tpu" else None
 
+    block = files.load_block(cfg)
     t0 = clk()
-    params = weights.build_program_params(m, seed)
+    params = weights.build_program_params(block.params_fn(m), seed)
     log(f"weights: made on {dev.device_kind} in one jitted call, "
         f"{clk() - t0:.3f} s")
     from repro.kernels import tune
@@ -335,7 +350,7 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
         "window_s": w1 - w0, "records": recs, "window": (w0, w1),
         "latency": lat, "first_token": waits, "per_token": tpot,
         "submit_lag": lags, "snapshots": snaps, "work": work,
-        "work_cost": probe.work_of(m, work), "peak": peak, "trace": None,
+        "work_cost": block.work_of(m, work), "peak": peak, "trace": None,
     }
     log(f"window: {w1 - w0:.3f} s, requests due {len(due_win)}, delivered "
         f"in it {len(in_win)}, decode steps {work.steps}, tokens decoded "
@@ -365,7 +380,7 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
                                              snaps["trace_t0"] <= r["done"]
                                              < snaps["trace_t1"]},
                                s["prefill_chunk"])
-        ctx["trace_work"] = probe.work_of(m, tw)
+        ctx["trace_work"] = block.work_of(m, tw)
 
     metrics = {}
     section = "per_layer" if do_trace else "end_to_end"
@@ -388,17 +403,21 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     # ------------------------------------------------------------- check
     del client.sched, sched, eng
     gc.collect()
+    limits = cfg["check"]
+    want = limits["checked_requests"]
     sample = _sample([r for r in recs if r["done"] is not None
                       and r["done"] >= w0 and r["status"] == "completed"
-                      and r["tokens"].size == r["n"]], seed)
-    limits = cfg["check"]
-    compared = {"checked_requests": (len(sample), SAMPLE)}
+                      and r["tokens"].size == r["n"]], seed, want)
+    compared = {"checked_requests": (len(sample), want)}
     if sample:
         t_ref = clk()
         gaps, ctl_gaps = reference_gaps(cfg, seed, sample, control)
         log(f"reference: {len(sample)} requests, "
             f"{sum(r['n'] for r in sample)} served tokens, "
             f"{clk() - t_ref:.3f} s")
+        log_gaps(log, sample, gaps, "program")
+        if control:
+            log_gaps(log, sample, ctl_gaps, "control")
         numbers = gap_numbers(gaps)
         if control:
             log(f"program's numbers (the control takes its place below): "
@@ -407,7 +426,7 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
         for name, value in numbers.items():
             compared[name] = (value, limits[name])
     compared["failed_requests"] = (len(failed), 0)
-    correct = (len(sample) == SAMPLE and
+    correct = (len(sample) == want and
                all(v <= lim for k, (v, lim) in compared.items()
                    if k != "checked_requests"))
     result = {

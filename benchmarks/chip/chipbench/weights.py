@@ -9,7 +9,10 @@ norm scales and biases) are drawn as normals.
 Each leaf of each layer has a key of its own, `leaf_key(seed, name,
 layer)`, so the program's stacked tree (one jitted call, all layers) and
 the plain reference (layer by layer, after the window) read the same
-values without either handing anything to the other.
+values without either handing anything to the other. Every draw is one of
+`packed_bits`, `packed_stack` or `normal`: a block module
+(`blocks/<name>.py`), which assembles the program's tree, and its
+reference call the same function with the same name, layer and shape.
 """
 from __future__ import annotations
 
@@ -36,16 +39,26 @@ def leaf_key(root, name: str, layer=None):
     return key if layer is None else jax.random.fold_in(key, layer)
 
 
-def binary_shapes(m: dict) -> dict[str, tuple[int, int]]:
-    """(K, N) of every binary weight of one block, by its path."""
-    d, h, kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                       m["head_dim"], m["d_ff"])
-    out = {"attn.wq": (d, h * hd), "attn.wk": (d, kv * hd),
-           "attn.wv": (d, kv * hd), "attn.wo": (h * hd, d),
-           "ffn.w_up": (d, f), "ffn.w_down": (f, d)}
+def attn_shapes(m: dict) -> dict[str, tuple[int, int]]:
+    """(K, N) of every binary attention weight of one block, by its path."""
+    d, h, kv, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    return {"attn.wq": (d, h * hd), "attn.wk": (d, kv * hd),
+            "attn.wv": (d, kv * hd), "attn.wo": (h * hd, d)}
+
+
+def ffn_shapes(m: dict, prefix: str = "ffn") -> dict[str, tuple[int, int]]:
+    """(K, N) of every binary weight of one FFN (or of each expert, with
+    prefix `ffn.experts`), by its path."""
+    d, f = m["d_model"], m["d_ff"]
+    out = {f"{prefix}.w_up": (d, f), f"{prefix}.w_down": (f, d)}
     if m["mlp"] in ("swiglu", "geglu"):
-        out["ffn.w_gate"] = (d, f)
+        out[f"{prefix}.w_gate"] = (d, f)
     return out
+
+
+def binary_shapes(m: dict) -> dict[str, tuple[int, int]]:
+    """(K, N) of every binary weight of one dense block, by its path."""
+    return {**attn_shapes(m), **ffn_shapes(m)}
 
 
 def norm_names(m: dict, prefix: str) -> list[str]:
@@ -62,22 +75,35 @@ def packed_bits(root, name: str, layer, k: int, n: int):
                            jnp.uint32)
 
 
+def packed_stack(root, name: str, layer, e: int, k: int, n: int):
+    """(E, N, K/32) uint32 sign words of one layer's E experts, from one
+    key: expert i's words are row i of the draw."""
+    assert k % WORD == 0, f"{name}: K={k} is not a whole number of words"
+    return jax.random.bits(leaf_key(root, name, layer), (e, n, k // WORD),
+                           jnp.uint32)
+
+
+def normal(root, name: str, layer, shape: tuple[int, ...], std: float):
+    """A float32 leaf of N(0, std^2) values."""
+    return std * jax.random.normal(leaf_key(root, name, layer), shape,
+                                   jnp.float32)
+
+
 def norm_vector(root, name: str, layer, d: int):
-    return NORM_STD * jax.random.normal(leaf_key(root, name, layer), (d,),
-                                        jnp.float32)
+    return normal(root, name, layer, (d,), NORM_STD)
 
 
 def embed_table(root, m: dict):
-    return EMBED_STD * jax.random.normal(
-        leaf_key(root, "embed"), (m["vocab"], m["d_model"]), jnp.float32)
+    return normal(root, "embed", None, (m["vocab"], m["d_model"]), EMBED_STD)
 
 
 def head_matrix(root, m: dict):
-    return HEAD_STD * jax.random.normal(
-        leaf_key(root, "lm_head"), (m["d_model"], m["vocab"]), jnp.float32)
+    return normal(root, "lm_head", None, (m["d_model"], m["vocab"]),
+                  HEAD_STD)
 
 
-def _nest(flat: dict) -> dict:
+def nest(flat: dict) -> dict:
+    """{"a.b": leaf} -> {"a": {"b": leaf}}."""
     out: dict = {}
     for path, leaf in flat.items():
         node = out
@@ -88,36 +114,8 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-def program_params_fn(m: dict):
-    """root key -> the program's frozen parameter tree: layer stacks of
-    packed words, fp32 embedding, head and norms — what `freeze(init(key))`
-    would hand the serving engine."""
-    from repro.core.packed import PackedWeight
-
-    n_layers, d = m["n_layers"], m["d_model"]
-    shapes = binary_shapes(m)
-
-    def build(root):
-        layers = jnp.arange(n_layers)
-        flat = {}
-        for name, (k, n) in shapes.items():
-            words = jax.vmap(lambda l: packed_bits(root, name, l, k, n))(
-                layers)
-            flat[name] = PackedWeight(words, k=k, kind="dense",
-                                      orig_dtype="float32")
-        for name in norm_names(m, "ln1") + norm_names(m, "ln2"):
-            flat[name] = jax.vmap(lambda l: norm_vector(root, name, l, d))(
-                layers)
-        top = {"embed": embed_table(root, m), "lm_head": head_matrix(root, m)}
-        for name in norm_names(m, "final_norm"):
-            top[name] = norm_vector(root, name, None, d)
-        return {**_nest(top), "blocks": _nest(flat)}
-
-    return build
-
-
-def build_program_params(m: dict, seed: int):
-    """The program's parameter tree, made on the default device in one
+def build_program_params(params_fn, seed: int):
+    """The program's parameter tree, `params_fn(root key)` of the
+    configuration's block module, made on the default device in one
     jitted call."""
-    return jax.block_until_ready(jax.jit(program_params_fn(m))(
-        root_key(seed)))
+    return jax.block_until_ready(jax.jit(params_fn)(root_key(seed)))

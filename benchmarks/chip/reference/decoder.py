@@ -8,7 +8,8 @@ states it:
   h      = embed[token] (+ sinusoidal position)                 [bf16]
   block  xn = norm(h); q, k, v = sign(xn) . sign(Wq|Wk|Wv)      [bf16]
          (RoPE on q and k where the configuration says rope)
-         score(i, j) = sign(q_i) . sign(k_j), j <= i
+         score(i, j) = sign(q_i) . sign(k_j), j <= i (and j > i - window
+                       where the configuration has a `local_window`)
          p(i, j)     = softmax(score / sqrt(hd)) held in 30-bit fixed
                        point: floor(2^30 * exp((score - max) / sqrt(hd)))
                        with exp built by a fixed chain of f32 products
@@ -25,6 +26,9 @@ operands, f32 sums), and every value the configuration keeps in bf16 is
 rounded to bf16 where it is made. `rnd` is that rounding; the control
 (`low=True`) rounds through float8_e5m2 first, the nearest precision
 below the configuration's bfloat16.
+
+`run` drives any layer stack from the seed: `reference/moe_decoder.py`
+gives it its own layer and weights and reuses the parts here.
 """
 from __future__ import annotations
 
@@ -97,8 +101,9 @@ def _rungs(hd: int) -> list[float]:
             for k in range(hd.bit_length())]
 
 
-def _attn_signs(q, k, v, hd: int, q_block: int):
-    """Sign of sum_j p(i, j) sign(v_j) per (row, query, head, dim), exact.
+def _attn_signs(q, k, v, hd: int, q_block: int, window: int = 0):
+    """Sign of sum_j p(i, j) sign(v_j) per (row, query, head, dim), exact,
+    over keys j <= i (and j > i - window for a window above 0).
     q (B, S, Hq, hd), k, v (B, S, Hkv, hd) as +-1 bf16."""
     b, s, hq, _ = q.shape
     g = hq // k.shape[2]
@@ -112,6 +117,8 @@ def _attn_signs(q, k, v, hd: int, q_block: int):
         pc = (hd - score) // 2
         qi = q0 + jnp.arange(qb.shape[2])[:, None]
         valid = jnp.arange(s)[None, :] <= qi
+        if window:
+            valid &= jnp.arange(s)[None, :] > qi - window
         pc = jnp.where(valid, pc, hd + 1)
         j = pc - jnp.min(pc, axis=-1, keepdims=True)
         e = jnp.ones(j.shape, jnp.float32)
@@ -129,7 +136,8 @@ def _attn_signs(q, k, v, hd: int, q_block: int):
     return jnp.concatenate(outs, axis=1)
 
 
-def _layer(h, positions, lw: dict, m: dict, low: bool, q_block: int):
+def _attend(h, positions, lw: dict, m: dict, low: bool, q_block: int):
+    """h after the block's attention and its residual add."""
     rnd = functools.partial(_rnd, low=low)
     b, s, d = h.shape
     hq, hkv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
@@ -140,18 +148,31 @@ def _layer(h, positions, lw: dict, m: dict, low: bool, q_block: int):
     if m["pos"] == "rope":
         q = rnd(_rope(q, positions, m["rope_theta"]))
         k = rnd(_rope(k, positions, m["rope_theta"]))
-    a = _attn_signs(_sign(q), _sign(k), _sign(v), hd, q_block)
-    h = rnd(h + rnd(_pm1_dot(a.reshape(b, s, hq * hd), lw["attn.wo"])))
-    xs = _sign(rnd(_norm(h, lw["ln2.scale"], lw.get("ln2.bias"), m)))
-    up = rnd(_pm1_dot(xs, lw["ffn.w_up"]))
+    a = _attn_signs(_sign(q), _sign(k), _sign(v), hd, q_block,
+                    m.get("local_window", 0))
+    return rnd(h + rnd(_pm1_dot(a.reshape(b, s, hq * hd), lw["attn.wo"])))
+
+
+def _mlp(xs, w_up, w_down, w_gate, m: dict, low: bool):
+    """One FFN's output in bf16 from the signs xs of its input."""
+    rnd = functools.partial(_rnd, low=low)
+    up = rnd(_pm1_dot(xs, w_up))
     if m["mlp"] == "gelu":
         f = rnd(jax.nn.gelu(up))
     elif m["mlp"] == "swiglu":
-        gate = rnd(_pm1_dot(xs, lw["ffn.w_gate"]))
+        gate = rnd(_pm1_dot(xs, w_gate))
         f = rnd(rnd(jax.nn.silu(gate)) * up)
     else:
         raise ValueError(f"reference has no mlp {m['mlp']!r}")
-    return rnd(h + rnd(_pm1_dot(_sign(f), lw["ffn.w_down"])))
+    return rnd(_pm1_dot(_sign(f), w_down))
+
+
+def _layer(h, positions, lw: dict, m: dict, low: bool, q_block: int):
+    rnd = functools.partial(_rnd, low=low)
+    h = _attend(h, positions, lw, m, low, q_block)
+    xs = _sign(rnd(_norm(h, lw["ln2.scale"], lw.get("ln2.bias"), m)))
+    return rnd(h + _mlp(xs, lw["ffn.w_up"], lw["ffn.w_down"],
+                        lw.get("ffn.w_gate"), m, low))
 
 
 def _layer_weights(root, m: dict, layer):
@@ -166,6 +187,14 @@ def logits(m: dict, seed: int, tokens: np.ndarray, *, low: bool = False,
            q_block: int = 512) -> np.ndarray:
     """(B, S) token ids -> (B, S, vocab) f32 logits of the reference (or of
     the control, `low=True`), computed one layer at a time."""
+    return run(m, seed, tokens, _layer_weights, _layer, low=low,
+               q_block=q_block)
+
+
+def run(m: dict, seed: int, tokens: np.ndarray, layer_weights, layer, *,
+        low: bool, q_block: int) -> np.ndarray:
+    """Logits of a stack whose every layer is `layer(h, positions,
+    layer_weights(root, m, index), m, low, q_block)`."""
     root = W.root_key(seed)
     tok = jnp.asarray(tokens, jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(tok.shape[1], dtype=jnp.int32),
@@ -179,9 +208,9 @@ def logits(m: dict, seed: int, tokens: np.ndarray, *, low: bool = False,
         return h
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def block(h, positions, layer):
-        return _layer(h, positions, _layer_weights(root, m, layer), m, low,
-                      q_block)
+    def block(h, positions, index):
+        return layer(h, positions, layer_weights(root, m, index), m, low,
+                     q_block)
 
     @jax.jit
     def head(h):
@@ -193,6 +222,6 @@ def logits(m: dict, seed: int, tokens: np.ndarray, *, low: bool = False,
                           precision=HIGHEST)
 
     h = embed(tok, positions)
-    for layer in range(m["n_layers"]):
-        h = block(h, positions, jnp.int32(layer))
+    for index in range(m["n_layers"]):
+        h = block(h, positions, jnp.int32(index))
     return np.asarray(jax.device_get(head(h)))

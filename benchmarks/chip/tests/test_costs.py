@@ -53,6 +53,35 @@ def test_attention_counts():
     assert a.bytes == 40 * (10 * 10 * 128 / 8 * 2 + 2 * 40 * 128 * 2 * 2)
 
 
+@pytest.mark.parametrize("window", [0, 1, 5, 64, 130, 4096])
+def test_windowed_keys_are_brute_force_sums(window):
+    """A query at position p scores min(p + 1, window) keys (every p + 1
+    at window 0); a chunk reads the positions some query of it sees."""
+    def cap(n):
+        return min(n, window) if window else n
+    for plen, first, last in [(100, 1, 4), (5, 1, 40), (60, 7, 9),
+                              (200, 10, 30), (3, 4, 4)]:
+        assert costs.decode_keys(plen, first, last, window) == \
+            sum(cap(plen + j) for j in range(first, last))
+    for lo, hi in [(0, 3), (0, 128), (128, 130), (60, 190), (256, 300)]:
+        assert costs.chunk_keys(lo, hi, window) == \
+            sum(cap(p + 1) for p in range(lo, hi))
+        seen = {k for p in range(lo, hi) for k in range(p + 1)
+                if not window or k > p - window}
+        assert costs.chunk_reads(lo, hi, window) == len(seen)
+
+
+def test_window_zero_is_the_old_count():
+    for plen, first, last in [(100, 1, 4), (5, 1, 40), (3, 4, 4),
+                              (7, 5, 2)]:
+        n = last - first
+        old = n * plen + (first + last - 1) * n // 2 if n > 0 else 0
+        assert costs.decode_keys(plen, first, last) == old
+    for lo, hi in [(0, 3), (128, 130), (60, 190)]:
+        assert costs.chunk_keys(lo, hi) == sum(range(lo + 1, hi + 1))
+        assert costs.chunk_reads(lo, hi) == hi
+
+
 def test_unknown_device_is_an_error():
     with pytest.raises(KeyError):
         costs.peaks("TPU v9 imaginary")
@@ -77,3 +106,13 @@ def test_window_work_from_snapshots():
     # a 128-token prefix hit
     assert w.chunks == 4 and w.chunk_rows == 128 + 44 + 100 + 128
     assert w.chunk_kv == 256 + 300 + 100 + 256
+    # the totals are the sums of what each request and chunk did
+    assert sorted(w.decodes) == [(100, 1, 2), (200, 10, 30), (300, 1, 5)]
+    assert sorted(w.spans) == [(0, 100), (128, 256), (128, 256),
+                               (256, 300)]
+    assert w.keys() == (w.decode_keys, w.chunk_keys, w.chunk_kv)
+    assert w.keys(64) == (
+        sum(costs.decode_keys(*d, 64) for d in w.decodes),
+        sum(costs.chunk_keys(*c, 64) for c in w.spans),
+        sum(costs.chunk_reads(*c, 64) for c in w.spans))
+    assert w.keys(64)[0] == 64 * (20 + 4 + 1)

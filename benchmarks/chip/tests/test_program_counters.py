@@ -35,8 +35,9 @@ def window():
     runner serves it: the snapshots at its edges and its records."""
     _, _, cfg, mix = tiny_cell(files.load_spec()["workloads"][0]["name"])
     m, s = cfg["model"], cfg["serving"]
-    eng = program.build_engine(cfg, weights.build_program_params(m, SEED),
-                               SEED)
+    params_fn = files.load_block(cfg).params_fn(m)
+    eng = program.build_engine(
+        cfg, weights.build_program_params(params_fn, SEED), SEED)
     sched = eng.scheduler()
     client = runner.Client(sched, time.perf_counter, spans=False)
     runner.compile_pass(client, mix, cfg, SEED)

@@ -54,3 +54,23 @@ def test_token_altered_where_produced_fails(monkeypatch):
     monkeypatch.setattr(sched_mod, "sample_tokens", altered)
     res, _ = run(CELLS[0])
     assert not res["correct"]
+
+
+def test_sample_holds_the_longest_and_the_rest_from_the_seed():
+    recs = [{"rid": i, "n": n} for i, n in enumerate([5, 9, 7, 9, 3, 8])]
+    a = runner._sample(recs, SEED, 4)
+    assert len(a) == 4 and len({r["rid"] for r in a}) == 4
+    assert a[0]["rid"] == 1                  # longest, ties to the lower rid
+    assert a == runner._sample(list(reversed(recs)), SEED, 4)
+    assert runner._sample(recs, SEED, 10) == sorted(
+        recs, key=lambda r: (-r["n"], r["rid"]))
+
+
+def test_fewer_requests_than_the_check_names_is_not_correct():
+    spec, c, cfg, mix = tiny_cell(CELLS[0])
+    cfg["check"]["checked_requests"] = 1000
+    res = runner.run_cell(spec, c, cfg, mix, SEED, 3.0, False,
+                          time.perf_counter(), lambda m: None)
+    assert not res["correct"]
+    got = res["compared"]["checked_requests"]
+    assert 0 < got["value"] < got["limit"] == 1000

@@ -1,10 +1,14 @@
 """Tiny cells for CPU runs of the harness: the configurations' shapes of
-block at toy widths, through the same files, runner and reference.
+block at toy widths (each block module's `tiny` cut), through the same
+files, runner and reference.
 
-Besides the benchmark's cells, `phi3-medium-14b.chat-shared` runs from
-files kept in `tests/data` (a cell left out of the benchmark, PERF.md), so
-the reference's GQA, SwiGLU, RMSNorm and RoPE paths stay tested against
-the program."""
+Besides the benchmark's cells, two run from files kept in `tests/data`:
+`phi3-medium-14b.chat-shared` (a cell left out of the benchmark, PERF.md),
+so the reference's GQA, SwiGLU, RMSNorm and RoPE paths stay tested against
+the program, and `moe-window.long-prompts`, a fixture of the program's
+`moe` family (8 experts, top-2, a 64-position window, prompts past it)
+that runs through `blocks/moe.py` and `reference/moe_decoder.py` and
+nothing else of its own."""
 from __future__ import annotations
 
 import copy
@@ -14,12 +18,12 @@ from pathlib import Path
 from chipbench import files
 
 DATA = Path(__file__).resolve().parent / "data"
-TINY_MODEL = dict(n_layers=2, d_model=2048, head_dim=64, d_ff=2048, vocab=2048)
 TINY_SERVING = dict(slots=4, max_len=512, pool_pages=24)
 # the compared number's limit at this size, from CPU readings of these
-# cells on three seeds: the program 0 to 1.1e-4, the control 0.021 to 2.8
-TINY_CHECK = {"logit_gap_mean": 0.005}
-DATA_CELLS = ["phi3-medium-14b.chat-shared"]
+# cells on three seeds: the program 0 to 1.1e-4, the control 0.021 to 2.8;
+# three requests compared, which a 3 s tiny window always delivers
+TINY_CHECK = {"checked_requests": 3, "logit_gap_mean": 0.005}
+DATA_CELLS = ["phi3-medium-14b.chat-shared", "moe-window.long-prompts"]
 
 
 def load_data(name: str) -> dict:
@@ -28,8 +32,7 @@ def load_data(name: str) -> dict:
 
 def tiny_cell(name: str) -> tuple[dict, dict, dict, dict]:
     """(spec, cell, config, mix) of a BENCHMARK.json cell or of one of
-    DATA_CELLS, cut to a size a CPU test holds: every width shrunk, heads
-    kept in their ratio."""
+    DATA_CELLS, cut to a size a CPU test holds by its block module."""
     spec = files.load_spec()
     if name in DATA_CELLS:
         config, mix_name = name.split(".", 1)
@@ -40,9 +43,7 @@ def tiny_cell(name: str) -> tuple[dict, dict, dict, dict]:
         cell = files.workload(spec, name)
         cfg = copy.deepcopy(files.load_config(spec, cell["config"]))
         mix = copy.deepcopy(files.load_traffic(cell["traffic"]))
-    m = cfg["model"]
-    group = m["n_heads"] // m["n_kv_heads"]
-    m.update(TINY_MODEL, n_heads=4, n_kv_heads=4 // min(group, 4))
+    cfg["model"] = files.load_block(cfg).tiny(cfg["model"])
     cfg["serving"].update(TINY_SERVING)
     cfg["check"] = dict(TINY_CHECK)
     mix["warmup_s"] = 1.0
